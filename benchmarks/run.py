@@ -79,6 +79,9 @@ def plan_overhead_record(rows, threshold: float, quick: bool) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     quick = os.environ.get("REPRO_BENCH_QUICK", "0") == "1"
     from . import (
         fig8_reachability,

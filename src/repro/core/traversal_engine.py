@@ -92,6 +92,7 @@ import numpy as np
 from repro.core import traversal as T
 from repro.core.compiled import EpochRegistry, pack_key
 from repro.core.graphview import GraphView
+from repro.kernels import resolve_interpret
 from repro.kernels.frontier import shard as FS
 from repro.kernels.frontier.ops import bfs_pallas, pack_edges_by_dst
 from repro.robust import faults
@@ -261,6 +262,9 @@ class TraversalEngine:
         # per-call degraded flag: set by _dispatch when a fallback backend
         # answered, read (and cleared) by the executor via consume_degraded
         self._last_degraded: Optional[str] = None
+        # the most recent exception a backend attempt raised (failover
+        # keeps the query alive; this keeps the cause inspectable)
+        self.last_error: Optional[BaseException] = None
         # sharded-backend knobs: mesh width (None = every visible device,
         # read per query so forced host-platform device counts apply) and
         # the auto policy's stream-size threshold for picking `sharded`
@@ -270,10 +274,7 @@ class TraversalEngine:
         self.block_edges = block_edges
         self.block_size = block_size
         # Pallas interpret mode: required off-TPU; overridable for tests
-        self.interpret = (
-            interpret if interpret is not None
-            else jax.default_backend() != "tpu"
-        )
+        self.interpret = resolve_interpret(interpret)
         self.lane_width = lane_width
         self.max_lanes = max_lanes  # widest single [S, V] sweep flush builds
         self._stats = collections.Counter()
@@ -517,7 +518,7 @@ class TraversalEngine:
                 try:
                     out = run_one(b)
                 except Exception as e:  # noqa: BLE001 - degrade, don't die
-                    last_err = e
+                    last_err = self.last_error = e
                     self._stats["backend_faults"] += 1
                     self._stats[f"backend_fault_{b}"] += 1
                     self.events["traversal_faults"] += 1
@@ -643,10 +644,13 @@ class TraversalEngine:
 
         hop = 0
         while hop < max_hops and frontier.any() and not targets_done(dist):
-            msgs = frontier[:, src]  # [S, E]
-            nxt_t = np.zeros((V, S), bool)
-            np.logical_or.at(nxt_t, dst, msgs.T)
-            nxt = nxt_t.T & ~visited & vmask[None, :]
+            # one lane at a time: an [S, E] message array does not fit
+            # host memory at Twitter scale, and 1-D fancy assignment is
+            # far faster than a 2-D ufunc.at
+            nxt = np.zeros((S, V), bool)
+            for s in np.flatnonzero(frontier.any(axis=1)):
+                nxt[s, dst[frontier[s, src]]] = True
+            nxt &= ~visited & vmask[None, :]
             dist = np.where(nxt, hop + 1, dist).astype(np.int32)
             visited |= nxt
             frontier = nxt
@@ -793,10 +797,11 @@ class TraversalEngine:
         dist[np.arange(S)[lanes], sp[lanes]] = 0.0
         dist = np.where(vmask[None, :], dist, np.inf).astype(np.float32)
         for _ in range(max_iters):
-            cand = (dist[:, src] + w[None, :]).astype(np.float32)
-            new_t = dist.T.copy()
-            np.minimum.at(new_t, dst, cand.T)
-            new = np.where(vmask[None, :], new_t.T, np.inf).astype(np.float32)
+            new = dist.copy()
+            for s in np.flatnonzero(np.isfinite(dist).any(axis=1)):
+                # 1-D ufunc.at per lane (numpy's fast path)
+                np.minimum.at(new[s], dst, (dist[s, src] + w).astype(np.float32))
+            new = np.where(vmask[None, :], new, np.inf).astype(np.float32)
             if not (new < dist).any():
                 break
             dist = new

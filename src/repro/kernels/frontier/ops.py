@@ -7,9 +7,13 @@ the scatter/dedup/distance epilogue in the kernel.
 """
 from __future__ import annotations
 
-import numpy as np
-import jax.numpy as jnp
+import functools
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.kernels import resolve_interpret
 from repro.kernels.frontier.kernel import frontier_hop
 from repro.kernels.frontier.ref import bfs_ref, frontier_hop_ref  # noqa: F401
 from repro.kernels.segment.ops import pack_segments
@@ -37,6 +41,21 @@ def pack_edges_by_dst(src, dst, n_vertices, *, block_rows=128, block_edges=256):
     return packed_src.astype(np.int32), packed_eid.astype(np.int32), ldst
 
 
+@functools.partial(
+    jax.jit, static_argnames=("block_rows", "block_edges", "interpret")
+)
+def _gather_hop(frontier, src_flat, ldst_k, visited, dist, hop, *,
+                block_rows, block_edges, interpret):
+    """One hop as one XLA program: the gather of frontier lanes by packed
+    edge source fuses with the kernel's input cast, so the f32 ``[S,
+    T*J*BE]`` message array is never materialized."""
+    msgs = jnp.take(frontier, src_flat, axis=1)
+    return frontier_hop(
+        msgs, ldst_k, visited, dist, hop,
+        block_rows=block_rows, block_edges=block_edges, interpret=interpret,
+    )
+
+
 def bfs_pallas(
     sources,  # int32 [S] vertex positions (-1 = inactive lane)
     packed_src: jnp.ndarray,  # [T, J, BE]
@@ -49,7 +68,7 @@ def bfs_pallas(
     *,
     block_rows: int = 128,
     max_hops: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
     delta_src=None,  # int32 [D] delta COO buffer (uncompacted inserts)
     delta_dst=None,
     delta_eid=None,
@@ -125,13 +144,18 @@ def bfs_pallas(
             )
         d_dst_idx = jnp.where(d_ok, delta_dst, VP)  # VP -> dropped
 
+    # lane-major state [S, VP]; the kernel layout is the flat packed stream
+    # (see kernel.py). A slot masked off above carries ldst -1, so its
+    # one-hot row is zero and the message it gathers never lands.
+    src_flat = src_safe.reshape(-1)
+    ldst_k = ldst_m.reshape(1, -1)
     frontier = (
-        jnp.zeros((VP, S), jnp.float32)
-        .at[sources, jnp.arange(S)]
+        jnp.zeros((S, VP), jnp.float32)
+        .at[jnp.arange(S), sources]
         .set(1.0, mode="drop")
     )
     if vertex_mask is not None:
-        frontier = frontier * vmask_p.astype(jnp.float32)[:, None]
+        frontier = frontier * vmask_p.astype(jnp.float32)[None, :]
     visited = frontier
     dist = jnp.where(frontier > 0, 0, -1).astype(jnp.int32)
 
@@ -145,17 +169,16 @@ def bfs_pallas(
         if not bool(jnp.any(frontier > 0)):
             break
         if tgt_c is not None:
-            found = dist[tgt_c, jnp.arange(S)] >= 0
+            found = dist[jnp.arange(S), tgt_c] >= 0
             found = found | (target_pos < 0) | (sources < 0)
             if bool(jnp.all(found)):
                 break
         prev = frontier
-        msgs = jnp.take(frontier, src_safe.reshape(-1), axis=0).reshape(T, J, BE, S)
-        msgs = msgs * src_ok[..., None]
-        frontier, dist, visited = frontier_hop(
-            msgs, ldst_m, visited, dist,
+        frontier, dist, visited = _gather_hop(
+            frontier, src_flat, ldst_k, visited, dist,
             jnp.full((1, 1), h, jnp.int32),
-            block_rows=block_rows, interpret=interpret,
+            block_rows=block_rows, block_edges=BE,
+            interpret=resolve_interpret(interpret),
         )
         if d_s is not None:
             # union in the delta edges' contribution to this hop: messages
@@ -163,10 +186,10 @@ def bfs_pallas(
             # not-yet-visited gate uses the kernel-updated visited set, so
             # a vertex reached by both main and delta gets hop h exactly
             # once — identical to one sweep over the concatenated stream
-            dmsg = jnp.take(prev, d_s, axis=0) * d_ok.astype(jnp.float32)[:, None]
+            dmsg = jnp.take(prev, d_s, axis=1) * d_ok.astype(jnp.float32)[None, :]
             dscat = (
-                jnp.zeros((VP, S), jnp.float32)
-                .at[d_dst_idx]
+                jnp.zeros((S, VP), jnp.float32)
+                .at[:, d_dst_idx]
                 .max(dmsg, mode="drop")
             )
             add = (dscat > 0) & (visited == 0)
@@ -174,4 +197,4 @@ def bfs_pallas(
             frontier = jnp.maximum(frontier, addf)
             visited = jnp.maximum(visited, addf)
             dist = jnp.where(add, h, dist)
-    return dist[:n_vertices].T
+    return dist[:, :n_vertices]

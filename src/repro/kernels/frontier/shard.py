@@ -14,10 +14,14 @@ kernel-layer half of that story:
 * :func:`sharded_bfs` / :func:`sharded_sssp_dist` — ``shard_map`` drivers
   over a 1-D ``"shards"`` mesh. Each device runs the scatter relaxation
   over *its* edge slice only; per-hop partial frontiers / distance arrays
-  are combined with the exact ring all-reduce
-  (:func:`repro.dist.compression.ring_allreduce_exact`), never the int8
-  error-feedback ring — frontier membership and min-fixpoint distances are
-  correctness-critical (see ``traversal_allreduce``'s lane guard).
+  are combined with XLA's all-reduce under an exact op (``pmax`` for the
+  frontier OR, ``pmin`` for distances), never the int8 error-feedback
+  ring — frontier membership and min-fixpoint distances are
+  correctness-critical (see ``traversal_allreduce``'s lane guard). The
+  hand-rolled ring (:func:`repro.dist.compression.ring_allreduce_exact`)
+  gives the same bits, but the TPU compiler spends minutes on it at
+  Twitter-cell widths (about 140 s for [16, 2^22] uint8 over a v5e 2x2),
+  against seconds for the native collective.
 
 Bit-identity argument (the differential suite asserts it at host-platform
 device counts 1/2/4): BFS combines per-shard boolean scatter-ORs — set
@@ -30,7 +34,7 @@ result bit-for-bit (min never rounds), so every iterate — and the
 ``changed`` stopping sequence — is identical to ``xla_coo``'s.
 
 The hop loops live *inside* one jitted ``shard_map`` call: state stays on
-device across hops, and the per-hop combine is device-to-device ring
+device across hops, and the per-hop combine is device-to-device collective
 traffic. Host transfers of shard_map outputs inside a hop loop are exactly
 what the ``cross-shard-host-transfer`` lint rule rejects.
 """
@@ -42,10 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist.compression import ring_allreduce_exact
 from repro.dist.sharding import TRAVERSAL_AXIS, edge_stream_specs
 
 _INF = jnp.float32(jnp.inf)
@@ -125,7 +127,7 @@ def _specs(*names):
 
 
 # --------------------------------------------------------------------------
-# BFS — per-shard scatter-OR, ring OR-combine each hop
+# BFS — per-shard scatter-OR, all-reduce OR-combine each hop
 # --------------------------------------------------------------------------
 def _bfs_body(
     src_l, dst_l, eid_l,  # [1, Epad] local edge slice (leading shard dim)
@@ -158,7 +160,7 @@ def _bfs_body(
     def expand(frontier):
         msgs = jnp.take(frontier, src_c, axis=1) * eok.astype(jnp.uint8)
         local = jnp.zeros_like(frontier).at[:, dst_l].max(msgs, mode="drop")
-        return ring_allreduce_exact(local, axis_name=TRAVERSAL_AXIS, op="or")
+        return jax.lax.pmax(local, TRAVERSAL_AXIS)  # exact OR over shards
 
     def targets_done(dist):
         if not has_targets:
@@ -200,9 +202,9 @@ def _sharded_bfs_fn(n_shards: int):
         body = functools.partial(
             _bfs_body, max_hops=max_hops, has_targets=has_targets
         )
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-            check_rep=False,  # ring ppermute combine defeats rep inference
+            check_vma=False,  # replication of the combined state is by construction
         )(ssrc, sdst, seid, dsrc, ddst, deid, source_pos, emask_rows,
           vmask, target_pos)
 
@@ -251,7 +253,7 @@ def sharded_bfs(
 
 
 # --------------------------------------------------------------------------
-# SSSP — per-shard scatter-min Jacobi rounds, ring MIN-combine each round
+# SSSP — per-shard scatter-min Jacobi rounds, all-reduce MIN-combine each round
 # --------------------------------------------------------------------------
 def _sssp_body(
     src_l, dst_l, eid_l,  # [1, Epad] local edge slice
@@ -282,7 +284,7 @@ def _sssp_body(
     def relax(dist):
         cand = jnp.take(dist, src_c, axis=1) + w_l[None, :]
         local = dist.at[:, dst_l].min(cand, mode="drop")
-        new = ring_allreduce_exact(local, axis_name=TRAVERSAL_AXIS, op="min")
+        new = jax.lax.pmin(local, TRAVERSAL_AXIS)  # float min never rounds
         return jnp.where(vmask[None, :], new, _INF)
 
     def cond(state):
@@ -313,9 +315,9 @@ def _sharded_sssp_fn(n_shards: int):
              emask_rows, vmask, *, max_iters):
         TRACE_COUNTS["traces_sssp_sharded"] += 1  # runs at trace time only
         body = functools.partial(_sssp_body, max_iters=max_iters)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(ssrc, sdst, seid, dsrc, ddst, deid, source_pos, weight_by_row,
           emask_rows, vmask)
 

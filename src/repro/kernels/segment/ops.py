@@ -28,7 +28,6 @@ def pack_segments(
     value arrays (weights, messages, masks).
     """
     seg_ids = np.asarray(seg_ids)
-    E = seg_ids.shape[0]
     T = -(-num_segments // block_rows)
     keep = (seg_ids >= 0) & (seg_ids < num_segments)
     tile_of = np.where(keep, seg_ids // block_rows, -1)
@@ -36,14 +35,15 @@ def pack_segments(
     J = max(1, int(-(-counts.max() // block_edges))) if counts.size else 1
     gather = np.full((T, J * block_edges), -1, np.int32)
     ldst = np.full((T, J * block_edges), -1, np.int32)
-    fill = np.zeros(T, np.int64)
-    order = np.arange(E)[keep]
-    for e in order:  # seg_ids sorted => sequential fill per tile
-        t = tile_of[e]
-        k = fill[t]
-        gather[t, k] = e
-        ldst[t, k] = seg_ids[e] - t * block_rows
-        fill[t] = k + 1
+    # seg_ids sorted => each tile's kept edges are contiguous in stream
+    # order; an edge's slot is its rank among the kept edges minus the
+    # number of kept edges in earlier tiles
+    e = np.flatnonzero(keep)
+    t = tile_of[e]
+    first = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    k = np.arange(e.shape[0]) - first[t]
+    gather[t, k] = e
+    ldst[t, k] = seg_ids[e] - t * block_rows
     return (
         gather.reshape(T, J, block_edges),
         ldst.reshape(T, J, block_edges),
@@ -59,7 +59,7 @@ def segment_sum(
     *,
     block_rows: int = 128,
     block_edges: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     vals = jnp.asarray(vals)
     gather, ldst, T, J = pack_segments(

@@ -2,7 +2,9 @@
 
 LM family: slot-based continuous-batching decode demo.
 Engine (grfusion): batched reachability query serving over a synthetic
-social graph — the paper-side serving path.
+power-law social graph — the paper-side serving path. ``--vertices`` and
+``--edges`` size the graph; the paper's Twitter-scale cell is
+``--vertices 4194304 --edges 33554432`` (``configs/grfusion.py``).
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -23,7 +26,12 @@ def main():
         choices=["auto", "xla_coo", "pallas_frontier", "reference", "sharded"],
         help="traversal backend for the graph-query serving path",
     )
+    ap.add_argument("--vertices", type=int, default=5000,
+                    help="graph size (engine only)")
+    ap.add_argument("--edges", type=int, default=25000,
+                    help="edge count (engine only)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     module = configs.get(args.arch)
     rng = jax.random.PRNGKey(0)
@@ -52,7 +60,8 @@ def main():
     from repro.data.synthetic import graph_tables, random_graph
     from repro.serve.engine import QueryServer
 
-    g = random_graph(5000, 25000, kind="powerlaw", seed=0)
+    V = args.vertices
+    g = random_graph(V, args.edges, kind="powerlaw", seed=0)
     vd, ed = graph_tables(g)
     eng = GRFusion(traversal_backend=args.backend or "auto")
     eng.create_table("V", vd)
@@ -63,7 +72,7 @@ def main():
                       backend=args.backend)
     rnp = np.random.default_rng(1)
     for _ in range(args.requests):
-        srv.submit(int(rnp.integers(0, 5000)), int(rnp.integers(0, 5000)))
+        srv.submit(int(rnp.integers(0, V)), int(rnp.integers(0, V)))
     results = srv.flush()
     reach = sum(r["reachable"] for r in results)
     stats = dict(eng.traversal.stats)

@@ -57,13 +57,15 @@ class LMServer:
             return False
         req.out = []
         self.active[slot] = req
-        # prefill token-by-token through the decode path (slot-local)
+        # prefill token-by-token through the decode path (slot-local).
+        # pos goes in as a copy: the host-to-device transfer of a numpy
+        # array may still be pending when the += below mutates it
         self.pos[slot] = 0
         for t in req.prompt:
             logits, self.cache = self._step(
                 self.params, self.cache,
                 jnp.asarray(self.last_tok)[:, None].at[slot].set(int(t)),
-                jnp.asarray(self.pos),
+                jnp.asarray(self.pos.copy()),
             )
             self.pos[slot] += 1
         self.last_tok[slot] = int(np.argmax(np.asarray(logits)[slot, 0]))
@@ -77,7 +79,7 @@ class LMServer:
             return []
         logits, self.cache = self._step(
             self.params, self.cache,
-            jnp.asarray(self.last_tok)[:, None], jnp.asarray(self.pos),
+            jnp.asarray(self.last_tok)[:, None], jnp.asarray(self.pos.copy()),
         )
         nxt = np.argmax(np.asarray(logits)[:, 0], axis=-1)
         done = []

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.compression import (
@@ -45,9 +44,9 @@ def _run_ring(n, per_shard, op, dtype):
     def body(x):
         return ring_allreduce_exact(x[0], axis_name=AXIS, op=op)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(AXIS, None),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return np.asarray(fn(jnp.asarray(per_shard, dtype)))
 
@@ -126,9 +125,9 @@ def test_traversal_allreduce_routes_modes():
             x[0], axis_name=AXIS, lane="agg", mode="int8_ef")
         return exact, agg
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(AXIS, None),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     x = jnp.asarray([[1.0, np.inf, 3.0]], jnp.float32)
     exact, agg = fn(x)
@@ -149,9 +148,9 @@ def test_int8_ring_still_serves_approximate_lanes(n):
     def body(x):
         return ring_allreduce_int8(x[0], axis_name=AXIS)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(AXIS, None),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     rng = np.random.default_rng(n)
     per_shard = rng.random((n, 64)).astype(np.float32)

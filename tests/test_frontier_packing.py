@@ -7,6 +7,7 @@ import numpy as np
 from _prop import given, settings, st
 
 from repro.kernels.frontier.ops import pack_edges_by_dst
+from repro.kernels.segment.ops import pack_segments
 
 
 def _roundtrip(src, dst, V, *, block_rows, block_edges):
@@ -80,3 +81,39 @@ def test_pack_drops_out_of_range_dsts_only():
     ps, pe, ldst = pack_edges_by_dst(src, dst, V, block_rows=4, block_edges=4)
     live = pe[pe >= 0]
     assert sorted(live.tolist()) == [0, 2]
+
+
+def _pack_fill_loop(seg_ids, num_segments, block_rows, block_edges):
+    """The per-edge sequential fill ``pack_segments`` replaced."""
+    T = -(-num_segments // block_rows)
+    keep = (seg_ids >= 0) & (seg_ids < num_segments)
+    tile_of = np.where(keep, seg_ids // block_rows, -1)
+    counts = np.bincount(tile_of[tile_of >= 0], minlength=T)
+    J = max(1, int(-(-counts.max() // block_edges))) if counts.size else 1
+    gather = np.full((T, J * block_edges), -1, np.int32)
+    ldst = np.full((T, J * block_edges), -1, np.int32)
+    fill = np.zeros(T, np.int64)
+    for e in np.arange(seg_ids.shape[0])[keep]:
+        t = tile_of[e]
+        gather[t, fill[t]] = e
+        ldst[t, fill[t]] = seg_ids[e] - t * block_rows
+        fill[t] += 1
+    return gather.reshape(T, J, block_edges), ldst.reshape(T, J, block_edges)
+
+
+@settings(max_examples=40, deadline=None)
+@given(
+    st.integers(1, 200),  # num_segments
+    st.integers(0, 600),  # edges
+    st.integers(0, 2**31 - 1),  # seed
+)
+def test_pack_fill_matches_sequential_loop(V, E, seed):
+    rng = np.random.default_rng(seed)
+    # sorted ids with -1 drops in front and out-of-range ids at the back
+    ids = np.sort(rng.integers(-1, V + 2, E)).astype(np.int32)
+    br, be = int(rng.choice([4, 8, 16])), int(rng.choice([2, 4, 8]))
+    gather, ldst, T, J = pack_segments(ids, V, block_rows=br, block_edges=be)
+    g_ref, l_ref = _pack_fill_loop(ids, V, br, be)
+    assert gather.dtype == g_ref.dtype and ldst.dtype == l_ref.dtype
+    assert np.array_equal(gather, g_ref) and np.array_equal(ldst, l_ref)
+    assert (T, J) == g_ref.shape[:2]

@@ -48,7 +48,7 @@ def _with_sketch_mode(fn):
             os.environ["REPRO_STATS_EXACT_MAX"] = old
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 10_000), st.integers(1, 64))
 def test_incremental_registers_bit_identical_to_rebuild(seed, k):
     def body():
@@ -90,7 +90,7 @@ def test_incremental_registers_bit_identical_to_rebuild(seed, k):
     _with_sketch_mode(body)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000))
 def test_incremental_estimate_within_5x_rse(seed):
     def body():

@@ -85,7 +85,7 @@ def _assert_views_equal(a, b):
 
 
 # -------------------------------------------------------------- properties
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(st.integers(0, 10_000), st.booleans())
 def test_merge_equals_rebuild_bit_for_bit(seed, directed):
     eng = _build_engine(seed, directed)
@@ -102,7 +102,7 @@ def test_merge_equals_rebuild_bit_for_bit(seed, directed):
     _assert_views_equal(merged, rebuilt)
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10_000), st.booleans())
 def test_delta_empty_after_compact(seed, directed):
     eng = _build_engine(seed, directed)
@@ -114,7 +114,7 @@ def test_delta_empty_after_compact(seed, directed):
     assert eng.events["compactions_merge"] == 1
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10_000), st.booleans())
 def test_edge_stream_invariant_across_compact(seed, directed):
     eng = _build_engine(seed, directed)
@@ -127,7 +127,7 @@ def test_edge_stream_invariant_across_compact(seed, directed):
         assert (xa == xb).all(), name
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000))
 def test_merge_then_full_rebuild_stable(seed):
     """Compacting an already-merged view is the identity (fixed point)."""

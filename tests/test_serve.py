@@ -11,7 +11,7 @@ from repro.serve.engine import LMServer, QueryServer, Request
 import jax.numpy as jnp
 
 
-def test_lm_server_matches_direct_decode():
+def _lm_server_vs_direct_decode():
     cfg = LMConfig(name="t", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
                    d_head=8, d_ff=64, vocab=31)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -39,6 +39,25 @@ def test_lm_server_matches_direct_decode():
     while not done:
         done = srv.step()
     assert req.out == out_direct
+
+
+
+def test_lm_server_matches_direct_decode():
+    _lm_server_vs_direct_decode()
+
+
+def test_lm_server_matches_direct_decode_with_compile_cache(tmp_path):
+    """The persistent cache changes dispatch timing; the server's host
+    buffers must not be read after it mutates them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    try:
+        _lm_server_vs_direct_decode()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()
 
 
 def test_query_server_batched_reachability():
