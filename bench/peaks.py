@@ -1,0 +1,28 @@
+"""Published peaks of the chips the benchmark runs on, keyed by the
+``device_kind`` JAX reports. A device that is not here is an error.
+
+Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+393 TOP/s int8, 16 GB HBM at 819 GB/s per chip).
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flop_per_s": 197e12,
+        "int8_op_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            "to bench/peaks.py with their source"
+        ) from None

@@ -1,0 +1,32 @@
+"""The reachability cell's check on the CPU at a tiny size: a clean run is
+correct, and each fault planted under the timed path makes it incorrect."""
+import pytest
+
+import planted_faults as faults
+import tiny_tree
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return tiny_tree.build(tmp_path_factory.mktemp("bench"), clients=8)
+
+
+def test_clean_run_is_correct(root, capsys):
+    rc, out = tiny_tree.run_cell(root, tiny_tree.REACH, seed=2**33 + 1, capsys=capsys)
+    assert rc == 0 and out["correct"] is True
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert out["compared"]["wrong_answers"] == {"value": 0, "limit": 0}
+    assert set(out["metrics"]) == {"setup_s", "queries_per_s", "peak_hbm_gib"}
+    assert list(out)[-1] == "compared"
+
+
+@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+def test_fault_makes_run_incorrect(root, capsys, monkeypatch, fault):
+    from bench import run
+
+    monkeypatch.setattr(run, "GRACE_S", 0.5)
+    faults.after_setup(monkeypatch, faults.FAULTS[fault])
+    rc, out = tiny_tree.run_cell(root, tiny_tree.REACH, seed=11, capsys=capsys)
+    assert rc == 0 and out["correct"] is False
+    compared = out["compared"]
+    assert compared["wrong_answers"]["value"] + compared["missing_answers"]["value"] > 0
