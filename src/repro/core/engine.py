@@ -44,6 +44,7 @@ from repro.core.graphview import GraphView, build_graph_view, merge_compact_view
 from repro.core.logical import DEFAULT_MAX_LEN
 from repro.core.table import Table, TableStats
 from repro.core.traversal_engine import TraversalEngine
+from repro.tracing import span
 
 __all__ = ["GRFusion", "QueryResult", "ViewBundle", "PreparedPlan", "GraphStats"]
 
@@ -99,16 +100,17 @@ class PreparedPlan:
     params: Dict[str, Any] = dfield(default_factory=dict)
 
     def bind(self, **params) -> "PreparedPlan":
-        unknown = sorted(set(params) - set(self.plan.param_names))
-        if unknown:
-            raise KeyError(
-                f"unknown parameter(s) {unknown}; this plan declares "
-                f"{sorted(self.plan.param_names) or 'none'}"
+        with span("grf.bind"):
+            unknown = sorted(set(params) - set(self.plan.param_names))
+            if unknown:
+                raise KeyError(
+                    f"unknown parameter(s) {unknown}; this plan declares "
+                    f"{sorted(self.plan.param_names) or 'none'}"
+                )
+            return PreparedPlan(
+                engine=self.engine, plan=self.plan,
+                params={**self.params, **params},
             )
-        return PreparedPlan(
-            engine=self.engine, plan=self.plan,
-            params={**self.params, **params},
-        )
 
     def execute(self) -> QueryResult:
         return EX.execute(self.plan, self.engine, params=self.params)
@@ -538,14 +540,15 @@ class GRFusion:
         side then swapped in one commit — a fault at any merge step
         leaves the old view queryable.
         """
-        if full:
-            return self.compact_view(name)
-        vb = self.views[name]
-        new_view = self._stage_merge(vb, vb.view, lambda n: self.tables[n])
-        self._commit(
-            views={name: new_view}, events={"compactions_merge": 1},
-            epoch_ops=(("main", name),),
-        )
+        with span("grf.compact"):
+            if full:
+                return self.compact_view(name)
+            vb = self.views[name]
+            new_view = self._stage_merge(vb, vb.view, lambda n: self.tables[n])
+            self._commit(
+                views={name: new_view}, events={"compactions_merge": 1},
+                epoch_ops=(("main", name),),
+            )
 
     def compact_view(self, name: str):
         """Full rebuild compaction (vertex-set changes, id updates, row

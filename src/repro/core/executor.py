@@ -40,6 +40,7 @@ from repro.core import operators as O
 from repro.core import query as Q
 from repro.core.logical import PathSpec, format_pathspec
 from repro.core.logical import pretty as _tree_pretty
+from repro.tracing import span, to_host
 
 
 @dataclass
@@ -211,7 +212,9 @@ class HashJoinExec(ExecNode):
         joined, ovf = O.join(
             lb, rb, self.left_key, self.right_key, capacity=self.capacity
         )
-        ctx.overflow = ctx.overflow or bool(ovf)
+        ctx.overflow = ctx.overflow or bool(
+            to_host(ovf, "join_overflow", ctx.engine.events)
+        )
         return joined
 
 
@@ -232,7 +235,9 @@ class CrossJoinExec(ExecNode):
         lb = self.left.run(ctx)
         rb = self.right.run(ctx)
         joined, ovf = O.cross_join(lb, rb, capacity=self.capacity)
-        ctx.overflow = ctx.overflow or bool(ovf)
+        ctx.overflow = ctx.overflow or bool(
+            to_host(ovf, "join_overflow", ctx.engine.events)
+        )
         ctx.explain.append(f"cross join with {self.right_alias} (bounded)")
         return joined
 
@@ -453,12 +458,13 @@ class PathScanExec(ExecNode):
             end_mask, targets = self._end_anchor_mask(ctx, vb, R)
             return sp, start_kind, sp_c, gvmask, hop_masks, end_mask, targets
 
-        epoch = (
-            _epoch_signature(ctx, self),
-            R is None,
-            _params_key(ctx),
-        )
-        return ctx.runtime.cached(("prep", self.spec.alias), epoch, build)
+        with span("grf.path.prepare"):
+            epoch = (
+                _epoch_signature(ctx, self),
+                R is None,
+                _params_key(ctx),
+            )
+            return ctx.runtime.cached(("prep", self.spec.alias), epoch, build)
 
     # -- execution ---------------------------------------------------------
     def run(self, ctx) -> O.RelBatch:
@@ -484,7 +490,7 @@ class PathScanExec(ExecNode):
         if spec.physical in ("bfs", "sssp", "bfs_path"):
             backend = eng.traversal.resolve_backend(
                 view, requested=spec.backend,
-                n_sources=int(start_pos.shape[0]),
+                n_sources=int(start_pos.shape[0]), graph=spec.graph,
             )
             ctx.explain.append(f"traversal backend: {backend}")
         elif spec.backend is not None:
@@ -502,116 +508,126 @@ class PathScanExec(ExecNode):
                     jnp.any(end_mask), jnp.argmax(end_mask), -1
                 ).astype(jnp.int32)
                 targets = jnp.broadcast_to(tpos, start_pos.shape)
-            dist = eng.traversal.bfs(
-                view, start_pos,
-                edge_mask_by_row=uniform_mask, vertex_mask=gvmask,
-                target_pos=targets,
-                max_hops=min(spec.max_len, eng.bfs_max_hops),
-                backend=backend, graph=spec.graph,
-            )
-            ctx.note_degraded(eng.traversal.consume_degraded())
-            tc = jnp.clip(targets, 0, view.n_vertices - 1)
-            d = jnp.take_along_axis(dist, tc[:, None], axis=1)[:, 0]
-            # validity: the lane must have live anchors on BOTH ends, and the
-            # distance must clear the minimum — OR be a 0-hop self-reach when
-            # min_len == 0. The grouping is load-bearing: without the inner
-            # parentheses a 0-distance lane with a dead anchor leaks through.
-            ok = (targets >= 0) & (start_pos >= 0) & (
-                (d >= spec.min_len) | ((d == 0) & (spec.min_len == 0))
-            )
-            ok = ok & (d >= 0)
-            cols = {
-                f"{a}.length": d,
-                f"{a}.exists": (d >= 0) & (targets >= 0),
-                f"{a}.startvertexid": jnp.take(view.v_ids, sp_c),
-                f"{a}.endvertexid": jnp.take(view.v_ids, tc),
-                f"{a}._start_pos": start_pos,
-                f"{a}._end_pos": targets,
-                f"{a}._origin": jnp.arange(start_pos.shape[0], dtype=jnp.int32),
-            }
-            pbatch = O.RelBatch(cols=cols, valid=ok)
+            with span("grf.traverse"):
+                dist = eng.traversal.bfs(
+                    view, start_pos,
+                    edge_mask_by_row=uniform_mask, vertex_mask=gvmask,
+                    target_pos=targets,
+                    max_hops=min(spec.max_len, eng.bfs_max_hops),
+                    backend=backend, graph=spec.graph,
+                )
+                ctx.note_degraded(eng.traversal.consume_degraded())
+            with span("grf.path.to_batch"):
+                tc = jnp.clip(targets, 0, view.n_vertices - 1)
+                d = jnp.take_along_axis(dist, tc[:, None], axis=1)[:, 0]
+                # validity: the lane must have live anchors on BOTH ends, and the
+                # distance must clear the minimum — OR be a 0-hop self-reach when
+                # min_len == 0. The grouping is load-bearing: without the inner
+                # parentheses a 0-distance lane with a dead anchor leaks through.
+                ok = (targets >= 0) & (start_pos >= 0) & (
+                    (d >= spec.min_len) | ((d == 0) & (spec.min_len == 0))
+                )
+                ok = ok & (d >= 0)
+                cols = {
+                    f"{a}.length": d,
+                    f"{a}.exists": (d >= 0) & (targets >= 0),
+                    f"{a}.startvertexid": jnp.take(view.v_ids, sp_c),
+                    f"{a}.endvertexid": jnp.take(view.v_ids, tc),
+                    f"{a}._start_pos": start_pos,
+                    f"{a}._end_pos": targets,
+                    f"{a}._origin": jnp.arange(start_pos.shape[0], dtype=jnp.int32),
+                }
+                pbatch = O.RelBatch(cols=cols, valid=ok)
         elif spec.physical in ("sssp", "bfs_path"):
             if spec.physical == "sssp":
                 wcol = vb.e_attrs.get(spec.sp_weight_attr, spec.sp_weight_attr)
                 w = et.col(wcol).astype(jnp.float32)
             else:
                 w = jnp.ones((et.capacity,), jnp.float32)
-            dist, parent = eng.traversal.sssp(
-                view, start_pos, w,
-                edge_mask_by_row=uniform_mask, vertex_mask=gvmask,
-                max_iters=64, backend=backend, graph=spec.graph,
-            )
-            ctx.note_degraded(eng.traversal.consume_degraded())
-            if targets is None and end_mask is not None and spec.end_anchor:
-                tpos = jnp.where(
-                    jnp.any(end_mask), jnp.argmax(end_mask), -1
-                ).astype(jnp.int32)
-                targets = jnp.broadcast_to(tpos, start_pos.shape)
-            if targets is not None:
-                tc = jnp.clip(targets, 0, view.n_vertices - 1)
-                d = jnp.take_along_axis(dist, tc[:, None], axis=1)[:, 0]
-                edges, verts, lens = eng.traversal.reconstruct_paths(
-                    view, parent, jnp.where(targets >= 0, targets, 0),
-                    max_len=min(max(spec.max_len, 8), 64),
+            with span("grf.traverse"):
+                dist, parent = eng.traversal.sssp(
+                    view, start_pos, w,
+                    edge_mask_by_row=uniform_mask, vertex_mask=gvmask,
+                    max_iters=64, backend=backend, graph=spec.graph,
                 )
-                ok = (targets >= 0) & (start_pos >= 0) & jnp.isfinite(d)
-                cols = {
-                    f"{a}.length": lens,
-                    f"{a}.distance": d,
-                    f"{a}.startvertexid": jnp.take(view.v_ids, sp_c),
-                    f"{a}.endvertexid": jnp.take(view.v_ids, tc),
-                    f"{a}._edges": edges,
-                    f"{a}._verts": verts,
-                    f"{a}._start_pos": start_pos,
-                    f"{a}._end_pos": targets,
-                    f"{a}._origin": jnp.arange(start_pos.shape[0], dtype=jnp.int32),
-                }
-                pbatch = O.RelBatch(cols=cols, valid=ok)
-            else:
-                # single-source, all destinations (Grail comparison shape)
-                d0 = dist[0]
-                ok = jnp.isfinite(d0) & view.v_valid
-                cols = {
-                    f"{a}.distance": d0,
-                    f"{a}.endvertexid": view.v_ids,
-                    f"{a}.startvertexid": jnp.broadcast_to(
-                        jnp.take(view.v_ids, sp_c[0]), (view.n_vertices,)
-                    ),
-                    f"{a}._end_pos": jnp.arange(view.n_vertices, dtype=jnp.int32),
-                    f"{a}._origin": jnp.zeros((view.n_vertices,), jnp.int32),
-                }
-                pbatch = O.RelBatch(cols=cols, valid=ok)
+                ctx.note_degraded(eng.traversal.consume_degraded())
+            with span("grf.path.to_batch"):
+                if targets is None and end_mask is not None and spec.end_anchor:
+                    tpos = jnp.where(
+                        jnp.any(end_mask), jnp.argmax(end_mask), -1
+                    ).astype(jnp.int32)
+                    targets = jnp.broadcast_to(tpos, start_pos.shape)
+                if targets is not None:
+                    tc = jnp.clip(targets, 0, view.n_vertices - 1)
+                    d = jnp.take_along_axis(dist, tc[:, None], axis=1)[:, 0]
+                    edges, verts, lens = eng.traversal.reconstruct_paths(
+                        view, parent, jnp.where(targets >= 0, targets, 0),
+                        max_len=min(max(spec.max_len, 8), 64),
+                    )
+                    ok = (targets >= 0) & (start_pos >= 0) & jnp.isfinite(d)
+                    cols = {
+                        f"{a}.length": lens,
+                        f"{a}.distance": d,
+                        f"{a}.startvertexid": jnp.take(view.v_ids, sp_c),
+                        f"{a}.endvertexid": jnp.take(view.v_ids, tc),
+                        f"{a}._edges": edges,
+                        f"{a}._verts": verts,
+                        f"{a}._start_pos": start_pos,
+                        f"{a}._end_pos": targets,
+                        f"{a}._origin": jnp.arange(start_pos.shape[0], dtype=jnp.int32),
+                    }
+                    pbatch = O.RelBatch(cols=cols, valid=ok)
+                else:
+                    # single-source, all destinations (Grail comparison shape)
+                    d0 = dist[0]
+                    ok = jnp.isfinite(d0) & view.v_valid
+                    cols = {
+                        f"{a}.distance": d0,
+                        f"{a}.endvertexid": view.v_ids,
+                        f"{a}.startvertexid": jnp.broadcast_to(
+                            jnp.take(view.v_ids, sp_c[0]), (view.n_vertices,)
+                        ),
+                        f"{a}._end_pos": jnp.arange(view.n_vertices, dtype=jnp.int32),
+                        f"{a}._origin": jnp.zeros((view.n_vertices,), jnp.int32),
+                    }
+                    pbatch = O.RelBatch(cols=cols, valid=ok)
         else:  # enumeration
-            ps = self._enumerate(ctx, vb, R, start_pos, end_mask, targets,
-                                 gvmask, hop_masks, count_only=False)
+            with span("grf.traverse"):
+                ps = self._enumerate(ctx, vb, R, start_pos, end_mask, targets,
+                                     gvmask, hop_masks, count_only=False)
+                ctx.overflow = ctx.overflow or bool(
+                    to_host(ps.overflow, "enum_overflow", eng.events)
+                )
             # view/vb may have been compacted inside _enumerate
             vb = eng.views[spec.graph]
             view = vb.view
-            ctx.overflow = ctx.overflow or bool(ps.overflow)
-            any_names = [f"any_{i}" for i in range(len(spec.any_edge_preds))]
-            pbatch = O.paths_to_batch(
-                ps, view, prefix=a + ".",
-                agg_names=[f"sum_{x}" for x in spec.agg_attrs],
-                any_names=any_names,
-            )
-            for an in any_names:  # ANY semantics: at least one edge passes
-                pbatch = pbatch.replace(
-                    valid=pbatch.valid & pbatch.col(f"{a}.{an}")
+            with span("grf.path.to_batch"):
+                any_names = [f"any_{i}" for i in range(len(spec.any_edge_preds))]
+                pbatch = O.paths_to_batch(
+                    ps, view, prefix=a + ".",
+                    agg_names=[f"sum_{x}" for x in spec.agg_attrs],
+                    any_names=any_names,
                 )
-            if targets is not None:
-                tgt_of_origin = jnp.take(
-                    targets, jnp.clip(ps.origin, 0, targets.shape[0] - 1)
-                )
-                pbatch = pbatch.replace(
-                    valid=pbatch.valid
-                    & (pbatch.col(f"{a}._end_pos") == tgt_of_origin)
-                )
+                for an in any_names:  # ANY semantics: at least one edge passes
+                    pbatch = pbatch.replace(
+                        valid=pbatch.valid & pbatch.col(f"{a}.{an}")
+                    )
+                if targets is not None:
+                    tgt_of_origin = jnp.take(
+                        targets, jnp.clip(ps.origin, 0, targets.shape[0] - 1)
+                    )
+                    pbatch = pbatch.replace(
+                        valid=pbatch.valid
+                        & (pbatch.col(f"{a}._end_pos") == tgt_of_origin)
+                    )
 
+        if R is None:
+            return pbatch
         # combine with the anchor child via the origin lane (§5.3). The
         # bfs/sssp target branches emit one output lane per child row with
         # origin == arange, so the gather is the identity there: merge the
         # child's columns directly instead of re-gathering every column.
-        if R is not None:
+        with span("grf.path.to_batch"):
             identity_origin = (
                 start_kind == "rel"
                 and spec.physical in ("bfs", "sssp", "bfs_path")
@@ -632,7 +648,6 @@ class PathScanExec(ExecNode):
                 else jnp.ones_like(pbatch.valid)
             )
             return O.RelBatch(cols=cols, valid=pbatch.valid & rv)
-        return pbatch
 
     def run_count(self, ctx):
         """COUNT(*)-fused traversal (aggregate-pushdown rule): no PathSet
@@ -645,8 +660,9 @@ class PathScanExec(ExecNode):
                 "traversal backend: request ignored (enumeration has a "
                 "single implementation)"
             )
-        return self._enumerate(ctx, vb, None, start_pos, None, None,
-                               gvmask, hop_masks, count_only=True)
+        with span("grf.traverse"):
+            return self._enumerate(ctx, vb, None, start_pos, None, None,
+                                   gvmask, hop_masks, count_only=True)
 
     def _enumerate(self, ctx, vb, R, start_pos, end_mask, targets, gvmask,
                    hop_masks, *, count_only):
@@ -657,11 +673,11 @@ class PathScanExec(ExecNode):
         view = vb.view
         n_src = int(start_pos.shape[0])
         wcap = OPT.choose_work_capacity(
-            spec, float(view.avg_fan_out), n_src,
+            spec, eng.traversal.fan_out(view, spec.graph), n_src,
             ctx.plan.query.bf_hint, max_cap=eng.max_work_capacity,
         )
         ctx.explain.append(f"enum work capacity: {wcap}")
-        if bool(jnp.any(view.delta_valid)):
+        if to_host(jnp.any(view.delta_valid), "delta_check", eng.events):
             eng.compact(spec.graph)
             vb = eng.views[spec.graph]
             view = vb.view
@@ -786,7 +802,9 @@ class PathJoinExec(ExecNode):
                 joined.col(self._key_col(la2, lw2))
                 == joined.col(self._key_col(ra2, rw2))
             )
-        ctx.overflow = ctx.overflow or bool(ovf)
+        ctx.overflow = ctx.overflow or bool(
+            to_host(ovf, "join_overflow", ctx.engine.events)
+        )
         ctx.explain.append(
             f"path join: {lkey} == {rkey} (build={self.build})"
         )
@@ -928,31 +946,35 @@ class ProjectExec(ExecNode):
         # result assembly: the query is over, moving the surviving rows
         # to host numpy here is the point of the method
         combined = self.child.run(ctx)
-        sel = self.select_list
-        if not sel:
-            keep = [k for k in combined.cols if not k.split(".")[-1].startswith("_")]
-            sel = {k: X.Col(k) for k in keep}
-        out_cols = {}
-        decode_info = {}
-        for out_name, e in sel.items():
-            vals, dec = eval_on_batch(ctx, e, combined, want_decode=True)
-            out_cols[out_name] = vals
-            decode_info[out_name] = dec
+        with span("grf.finalize"):
+            sel = self.select_list
+            if not sel:
+                keep = [k for k in combined.cols if not k.split(".")[-1].startswith("_")]
+                sel = {k: X.Col(k) for k in keep}
+            out_cols = {}
+            decode_info = {}
+            for out_name, e in sel.items():
+                vals, dec = eval_on_batch(ctx, e, combined, want_decode=True)
+                out_cols[out_name] = vals
+                decode_info[out_name] = dec
 
-        validm = np.asarray(combined.valid)
-        order = np.argsort(~validm, kind="stable")  # valid rows first
-        n = int(validm.sum())
-        final = {}
-        for k, v in out_cols.items():
-            arr = np.asarray(v)[order][:n] if np.ndim(v) else np.asarray(v)
-            dec = decode_info.get(k)
-            if dec is not None and np.ndim(arr):
-                arr = ctx.engine.decode_column(dec[0], dec[1], arr)
-            final[k] = arr
-        return QueryResult(
-            columns=final, count=n, explain=ctx.explain, overflow=ctx.overflow,
-            degraded_backend=ctx.degraded_backend,
-        )
+            validm, *vals = to_host(
+                [combined.valid, *out_cols.values()], "finalize",
+                ctx.engine.events,
+            )
+            order = np.argsort(~validm, kind="stable")  # valid rows first
+            n = int(validm.sum())
+            final = {}
+            for k, v in zip(out_cols, vals):  # in SELECT order
+                arr = np.asarray(v)[order][:n] if np.ndim(v) else np.asarray(v)
+                dec = decode_info.get(k)
+                if dec is not None and np.ndim(arr):
+                    arr = ctx.engine.decode_column(dec[0], dec[1], arr)
+                final[k] = arr
+            return QueryResult(
+                columns=final, count=n, explain=ctx.explain, overflow=ctx.overflow,
+                degraded_backend=ctx.degraded_backend,
+            )
 
 
 @dataclass
@@ -971,30 +993,34 @@ class AggregateExec(ExecNode):
         # result assembly: scalar aggregates land on host by design
         if isinstance(self.child, PathScanExec) and self.child.spec.count_only:
             cnt, ovf = self.child.run_count(ctx)
-            cols = {name: np.asarray(cnt) for name in self.agg_select}
+            with span("grf.finalize"):
+                cnt, ovf = to_host((cnt, ovf), "finalize", ctx.engine.events)
+                cols = {name: np.asarray(cnt) for name in self.agg_select}
+                return QueryResult(
+                    columns=cols, count=1, explain=ctx.explain,
+                    overflow=ctx.overflow or bool(ovf),
+                    degraded_backend=ctx.degraded_backend,
+                )
+        combined = self.child.run(ctx)
+        with span("grf.finalize"):
+            aggs = {}
+            for name, (op, e) in self.agg_select.items():
+                if op == "count":
+                    aggs[name] = jnp.sum(combined.valid.astype(jnp.int32))
+                    continue
+                vals = eval_on_batch(ctx, e, combined)
+                v = combined.valid
+                if op == "sum":
+                    aggs[name] = jnp.sum(jnp.where(v, vals, 0))
+                elif op == "min":
+                    aggs[name] = jnp.min(jnp.where(v, vals, jnp.inf))
+                elif op == "max":
+                    aggs[name] = jnp.max(jnp.where(v, vals, -jnp.inf))
+            vals = to_host(list(aggs.values()), "finalize", ctx.engine.events)
             return QueryResult(
-                columns=cols, count=1, explain=ctx.explain,
-                overflow=ctx.overflow or bool(ovf),
+                columns=dict(zip(aggs, vals)), count=1, explain=ctx.explain, overflow=ctx.overflow,
                 degraded_backend=ctx.degraded_backend,
             )
-        combined = self.child.run(ctx)
-        aggs = {}
-        for name, (op, e) in self.agg_select.items():
-            if op == "count":
-                aggs[name] = np.asarray(jnp.sum(combined.valid.astype(jnp.int32)))
-                continue
-            vals = eval_on_batch(ctx, e, combined)
-            v = combined.valid
-            if op == "sum":
-                aggs[name] = np.asarray(jnp.sum(jnp.where(v, vals, 0)))
-            elif op == "min":
-                aggs[name] = np.asarray(jnp.min(jnp.where(v, vals, jnp.inf)))
-            elif op == "max":
-                aggs[name] = np.asarray(jnp.max(jnp.where(v, vals, -jnp.inf)))
-        return QueryResult(
-            columns=aggs, count=1, explain=ctx.explain, overflow=ctx.overflow,
-            degraded_backend=ctx.degraded_backend,
-        )
 
 
 # --------------------------------------------------------------------------
@@ -1129,22 +1155,23 @@ def execute(plan, engine, params=None) -> QueryResult:
     """
     from repro.core.compiled import PlanRuntime
 
-    params = dict(params or {})
-    missing = [p for p in getattr(plan, "param_names", ()) if p not in params]
-    if missing:
-        raise ValueError(
-            f"unbound parameter(s) {missing}; call PreparedPlan.bind(...) "
-            "before executing"
+    with span("grf.execute"):
+        params = dict(params or {})
+        missing = [p for p in getattr(plan, "param_names", ()) if p not in params]
+        if missing:
+            raise ValueError(
+                f"unbound parameter(s) {missing}; call PreparedPlan.bind(...) "
+                "before executing"
+            )
+        rt = plan.runtime
+        if rt is None or rt.engine is not engine:
+            rt = PlanRuntime(engine)
+            plan.runtime = rt
+        ctx = ExecContext(
+            engine=engine, plan=plan, runtime=rt, params=params,
+            explain=list(plan.explain_lines()),
         )
-    rt = plan.runtime
-    if rt is None or rt.engine is not engine:
-        rt = PlanRuntime(engine)
-        plan.runtime = rt
-    ctx = ExecContext(
-        engine=engine, plan=plan, runtime=rt, params=params,
-        explain=list(plan.explain_lines()),
-    )
-    root = plan.root
-    if not hasattr(root, "finalize"):
-        raise TypeError(f"plan root {type(root).__name__} is not a finalizer")
-    return root.finalize(ctx)
+        root = plan.root
+        if not hasattr(root, "finalize"):
+            raise TypeError(f"plan root {type(root).__name__} is not a finalizer")
+        return root.finalize(ctx)

@@ -120,6 +120,29 @@ def bfs(
     ``unroll_hops`` replaces the early-exit while loop with a fixed
     unrolled sweep (dry-run cost accounting; XLA counts loop bodies once).
     """
+    return bfs_hops(
+        view, source_pos, edge_mask_by_row, vertex_mask, target_pos,
+        max_hops=max_hops, block_size=block_size, unroll_hops=unroll_hops,
+        state_spec=state_spec, dist_dtype=dist_dtype,
+    )[0]
+
+
+def bfs_hops(
+    view: GraphView,
+    source_pos: jnp.ndarray,
+    edge_mask_by_row: jnp.ndarray | None = None,
+    vertex_mask: jnp.ndarray | None = None,
+    target_pos: jnp.ndarray | None = None,
+    *,
+    max_hops: int = 32,
+    block_size: int = 1 << 16,
+    unroll_hops: bool = False,
+    state_spec=None,
+    dist_dtype: str = "int32",
+):
+    """``bfs`` (unjitted) that also returns the hops the sweep ran: (dist
+    [S, V], hops int32 scalar). ``TraversalEngine`` jits this one and counts
+    the hops without a host sync per query."""
     V = view.n_vertices
     S = source_pos.shape[0]
     vmask = view.v_valid if vertex_mask is None else (view.v_valid & vertex_mask)
@@ -150,8 +173,9 @@ def bfs(
 
     def expand(frontier):
         def body(i, nxt):
-            msgs = jnp.take(frontier, src_c[i], axis=1) * emask_b[i].astype(jnp.uint8)
-            return nxt.at[:, dst_b[i]].max(msgs, mode="drop")
+            with jax.named_scope("grf.bfs.block"):
+                msgs = jnp.take(frontier, src_c[i], axis=1) * emask_b[i].astype(jnp.uint8)
+                return nxt.at[:, dst_b[i]].max(msgs, mode="drop")
 
         if unroll_hops:  # fixed-shape accounting: unroll the block loop too
             nxt = jnp.zeros_like(frontier)
@@ -173,21 +197,22 @@ def bfs(
         return (hop < max_hops) & jnp.any(frontier > 0) & ~targets_done(dist)
 
     def step(state):
-        frontier, visited, dist, hop = state
-        nxt = expand(frontier)
-        nxt = constrain(nxt * (1 - visited) * vmask.astype(jnp.uint8)[None, :])
-        dist = constrain(jnp.where(nxt > 0, (hop + 1).astype(ddt), dist))
-        return nxt, constrain(visited | nxt), dist, hop + 1
+        with jax.named_scope("grf.bfs.hop"):
+            frontier, visited, dist, hop = state
+            nxt = expand(frontier)
+            nxt = constrain(nxt * (1 - visited) * vmask.astype(jnp.uint8)[None, :])
+            dist = constrain(jnp.where(nxt > 0, (hop + 1).astype(ddt), dist))
+            return nxt, constrain(visited | nxt), dist, hop + 1
 
     if unroll_hops:
         state = (frontier0, frontier0, dist0, jnp.int32(0))
         for _ in range(max_hops):
             state = step(state)
-        return state[2]
-    _, _, dist, _ = jax.lax.while_loop(
+        return state[2], state[3]
+    _, _, dist, hop = jax.lax.while_loop(
         cond, step, (frontier0, frontier0, dist0, jnp.int32(0))
     )
-    return dist
+    return dist, hop
 
 
 # --------------------------------------------------------------------------
@@ -481,59 +506,60 @@ def enumerate_paths(
         res = emit(jnp.int32(0), end, verts, edges, agg, anyf, origin, alive, res)
 
     for h in range(max_len):
-        counts = jnp.where(alive, jnp.take(view.fan_out, end), 0)
-        parent, within, vslot, total = expand_by_counts(counts, W)
-        work_ovf = total > W
-        eslot = jnp.take(view.out_offsets, jnp.take(end, parent)) + within
-        eslot = jnp.clip(eslot, 0, view.out_eid.shape[0] - 1)
-        erow = jnp.take(view.out_eid, eslot)
-        ndst = jnp.take(view.out_dst, eslot)
+        with jax.named_scope("grf.enum.hop"):
+            counts = jnp.where(alive, jnp.take(view.fan_out, end), 0)
+            parent, within, vslot, total = expand_by_counts(counts, W)
+            work_ovf = total > W
+            eslot = jnp.take(view.out_offsets, jnp.take(end, parent)) + within
+            eslot = jnp.clip(eslot, 0, view.out_eid.shape[0] - 1)
+            erow = jnp.take(view.out_eid, eslot)
+            ndst = jnp.take(view.out_dst, eslot)
 
-        ok = vslot & (erow >= 0) & (ndst < V)
-        erc = jnp.clip(erow, 0, max(ecap - 1, 0))
-        if hop_edge_masks is not None:
-            ok = ok & jnp.take(hop_edge_masks[h], erc)
-        ndc = jnp.clip(ndst, 0, V - 1)
-        ok = ok & jnp.take(vmask, ndc)
+            ok = vslot & (erow >= 0) & (ndst < V)
+            erc = jnp.clip(erow, 0, max(ecap - 1, 0))
+            if hop_edge_masks is not None:
+                ok = ok & jnp.take(hop_edge_masks[h], erc)
+            ndc = jnp.clip(ndst, 0, V - 1)
+            ok = ok & jnp.take(vmask, ndc)
 
-        pv = jnp.take(verts, parent, axis=0)  # [W, Lmax+1]
-        # simple-path: never revisit interior vertices; the start vertex may
-        # only be revisited on the closing hop of a loop query.
-        revisit_interior = jnp.any(pv[:, 1 : h + 1] == ndst[:, None], axis=1) if h >= 1 else jnp.zeros((W,), jnp.bool_)
-        ok = ok & ~revisit_interior
-        at_start = pv[:, 0] == ndst
-        if close_loop and h == max_len - 1:
-            ok = ok & at_start
-        else:
-            ok = ok & ~at_start
+            pv = jnp.take(verts, parent, axis=0)  # [W, Lmax+1]
+            # simple-path: never revisit interior vertices; the start vertex may
+            # only be revisited on the closing hop of a loop query.
+            revisit_interior = jnp.any(pv[:, 1 : h + 1] == ndst[:, None], axis=1) if h >= 1 else jnp.zeros((W,), jnp.bool_)
+            ok = ok & ~revisit_interior
+            at_start = pv[:, 0] == ndst
+            if close_loop and h == max_len - 1:
+                ok = ok & at_start
+            else:
+                ok = ok & ~at_start
 
-        nagg = jnp.take(agg, parent, axis=0)
-        if n_agg:
-            wrow = agg_weights[:, erc].T  # [W, n_agg]
-            nagg = nagg + wrow
-            if agg_upper_bounds is not None:
-                ok = ok & jnp.all(nagg <= agg_upper_bounds[None, :], axis=1)
-        nany = jnp.take(anyf, parent, axis=0)
-        if n_any:
-            nany = nany | any_masks[:, erc].T
+            nagg = jnp.take(agg, parent, axis=0)
+            if n_agg:
+                wrow = agg_weights[:, erc].T  # [W, n_agg]
+                nagg = nagg + wrow
+                if agg_upper_bounds is not None:
+                    ok = ok & jnp.all(nagg <= agg_upper_bounds[None, :], axis=1)
+            nany = jnp.take(anyf, parent, axis=0)
+            if n_any:
+                nany = nany | any_masks[:, erc].T
 
-        nedges = jnp.take(edges, parent, axis=0).at[:, h].set(jnp.where(ok, erow, -1))
-        nverts = pv.at[:, h + 1].set(jnp.where(ok, ndst, -1))
+            nedges = jnp.take(edges, parent, axis=0).at[:, h].set(jnp.where(ok, erow, -1))
+            nverts = pv.at[:, h + 1].set(jnp.where(ok, ndst, -1))
 
-        norigin = jnp.take(origin, parent)
+            norigin = jnp.take(origin, parent)
 
-        tgt, kept, ovf = compact_targets(ok, W)
-        end = jnp.zeros((W,), jnp.int32).at[tgt].set(ndc, mode="drop")
-        verts = jnp.full((W, Lmax + 1), -1, jnp.int32).at[tgt].set(nverts, mode="drop")
-        edges = jnp.full((W, Lmax), -1, jnp.int32).at[tgt].set(nedges, mode="drop")
-        agg = jnp.zeros_like(agg).at[tgt].set(nagg, mode="drop")
-        anyf = jnp.zeros_like(anyf).at[tgt].set(nany, mode="drop")
-        origin = jnp.full((W,), -1, jnp.int32).at[tgt].set(norigin, mode="drop")
-        alive = jnp.zeros((W,), jnp.bool_).at[tgt].set(ok, mode="drop")
-        res = res[:7] + (res[7] | ovf | work_ovf, res[8])
+            tgt, kept, ovf = compact_targets(ok, W)
+            end = jnp.zeros((W,), jnp.int32).at[tgt].set(ndc, mode="drop")
+            verts = jnp.full((W, Lmax + 1), -1, jnp.int32).at[tgt].set(nverts, mode="drop")
+            edges = jnp.full((W, Lmax), -1, jnp.int32).at[tgt].set(nedges, mode="drop")
+            agg = jnp.zeros_like(agg).at[tgt].set(nagg, mode="drop")
+            anyf = jnp.zeros_like(anyf).at[tgt].set(nany, mode="drop")
+            origin = jnp.full((W,), -1, jnp.int32).at[tgt].set(norigin, mode="drop")
+            alive = jnp.zeros((W,), jnp.bool_).at[tgt].set(ok, mode="drop")
+            res = res[:7] + (res[7] | ovf | work_ovf, res[8])
 
-        if (h + 1) >= min_len and (not close_loop or (h + 1) == max_len):
-            res = emit(jnp.int32(h + 1), end, verts, edges, agg, anyf, origin, alive, res)
+            if (h + 1) >= min_len and (not close_loop or (h + 1) == max_len):
+                res = emit(jnp.int32(h + 1), end, verts, edges, agg, anyf, origin, alive, res)
 
     (r_edges, r_verts, r_len, r_agg, r_any, r_origin, r_count, overflow, count_total) = res
     if count_only:

@@ -96,6 +96,7 @@ from repro.kernels import resolve_interpret
 from repro.kernels.frontier import shard as FS
 from repro.kernels.frontier.ops import bfs_pallas, pack_edges_by_dst
 from repro.robust import faults
+from repro.tracing import to_host
 
 BACKENDS = ("xla_coo", "pallas_frontier", "reference", "sharded")
 _INF = jnp.float32(jnp.inf)
@@ -138,17 +139,21 @@ SHARD_MIN_SLOTS = 1 << 22
 _TRACE_COUNTS: collections.Counter = collections.Counter()
 
 
-def _trace_counted(fn, key, static_argnames=()):
+def _trace_counted(fn, key, static_argnames=(), name=None):
     def inner(*a, **k):
         _TRACE_COUNTS[key] += 1  # runs at trace time only
         return fn(*a, **k)
 
     functools.update_wrapper(inner, fn)
+    if name is not None:  # the XLA module is named jit_<name>
+        inner.__name__ = inner.__qualname__ = name
     return jax.jit(inner, static_argnames=static_argnames)
 
 
+# T.bfs with its hop count; compiled as ``jit_bfs``, the module name the
+# benchmark's trace reduction reads
 _bfs_xla = _trace_counted(
-    T.bfs.__wrapped__, "traces_bfs_xla", T.BFS_STATIC_ARGNAMES
+    T.bfs_hops, "traces_bfs_xla", T.BFS_STATIC_ARGNAMES, name="bfs"
 )
 _sssp_xla = _trace_counted(
     T.sssp.__wrapped__, "traces_sssp_xla", T.SSSP_STATIC_ARGNAMES
@@ -278,6 +283,10 @@ class TraversalEngine:
         self.lane_width = lane_width
         self.max_lanes = max_lanes  # widest single [S, V] sweep flush builds
         self._stats = collections.Counter()
+        # xla_coo hops, summed on the device until ``stats`` reads them
+        self._hops = jnp.zeros((), jnp.int32)
+        # graph -> (topology key, avg_fan_out as a host float)
+        self._fan_out: Dict[str, Tuple[Tuple, float]] = {}
         self._packs: "collections.OrderedDict" = collections.OrderedDict()
         self._shard_packs: "collections.OrderedDict" = collections.OrderedDict()
         self._pack_cap = pack_cache_capacity
@@ -293,8 +302,22 @@ class TraversalEngine:
 
     @property
     def stats(self) -> collections.Counter:
-        """Per-engine event counts merged with the shared trace counters."""
-        return self._stats + _TRACE_COUNTS + FS.TRACE_COUNTS
+        """Per-engine event counts merged with the shared trace counters.
+        ``hops_xla_coo`` sums the hops every ``xla_coo`` BFS sweep ran."""
+        hops = collections.Counter(hops_xla_coo=int(jax.device_get(self._hops)))
+        return self._stats + hops + _TRACE_COUNTS + FS.TRACE_COUNTS
+
+    def fan_out(self, view: GraphView, graph: Optional[str] = None) -> float:
+        """``view.avg_fan_out`` as a host float, read once per topology
+        epoch of a registered graph (every read for a standalone view)."""
+        if graph is None or not self.epochs.known(graph):
+            return float(to_host(view.avg_fan_out, "fan_out", self.events))
+        key = self.topology_key(view, graph)
+        ent = self._fan_out.get(graph)
+        if ent is None or ent[0] != key:
+            ent = (key, float(to_host(view.avg_fan_out, "fan_out", self.events)))
+            self._fan_out[graph] = ent
+        return ent[1]
 
     # ------------------------------------------------------- topology epochs
     def register_view(self, name: str):
@@ -460,6 +483,7 @@ class TraversalEngine:
         *,
         requested: Optional[str] = None,
         n_sources: int = 1,
+        graph: Optional[str] = None,
     ) -> str:
         """Auto policy: device-count-aware frontier-density heuristic.
 
@@ -484,7 +508,7 @@ class TraversalEngine:
             if n_slots >= self.shard_min_slots:
                 return "sharded"
         if jax.default_backend() == "tpu":
-            dense = float(view.avg_fan_out) >= 4.0 and n_sources >= 8
+            dense = self.fan_out(view, graph) >= 4.0 and n_sources >= 8
             if dense:
                 return "pallas_frontier"
         return "xla_coo"
@@ -556,7 +580,8 @@ class TraversalEngine:
         failing the query (see ``_dispatch``)."""
         source_pos = jnp.asarray(source_pos, jnp.int32)
         b = self.resolve_backend(
-            view, requested=backend, n_sources=int(source_pos.shape[0])
+            view, requested=backend, n_sources=int(source_pos.shape[0]),
+            graph=graph,
         )
         self._stats["queries_bfs"] += 1
         return self._dispatch(
@@ -574,16 +599,20 @@ class TraversalEngine:
         """One BFS on one specific backend (the failover unit)."""
         faults.check(SITE_DISPATCH[b])
         if b == "xla_coo":
-            return _bfs_xla(
+            dist, hops = _bfs_xla(
                 view, source_pos, edge_mask_by_row, vertex_mask,
                 target_pos, max_hops=max_hops, block_size=self._block_for(view),
             )
+            self._hops = self._hops + hops
+            return dist
         if b == "pallas_frontier":
             ps, pe, ldst = self.get_pack(view, graph)
             vmask = view.v_valid if vertex_mask is None else (
                 view.v_valid & vertex_mask
             )
-            has_delta = bool(jnp.any(view.delta_valid))
+            has_delta = bool(
+                to_host(jnp.any(view.delta_valid), "delta_check", self.events)
+            )
             return bfs_pallas(
                 source_pos, ps, pe, ldst, view.n_vertices,
                 edge_mask_by_row=edge_mask_by_row,
@@ -677,7 +706,8 @@ class TraversalEngine:
         source_pos = jnp.asarray(source_pos, jnp.int32)
         weight_by_row = jnp.asarray(weight_by_row, jnp.float32)
         b = self.resolve_backend(
-            view, requested=backend, n_sources=int(source_pos.shape[0])
+            view, requested=backend, n_sources=int(source_pos.shape[0]),
+            graph=graph,
         )
         self._stats["queries_sssp"] += 1
         return self._dispatch(

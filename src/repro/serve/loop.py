@@ -56,6 +56,7 @@ from dataclasses import dataclass, field as dfield
 from typing import Any, Callable, Dict, List, Optional
 
 from repro.robust.faults import TransientFault
+from repro.tracing import span
 
 __all__ = ["Ticket", "QueryLoop"]
 
@@ -160,50 +161,51 @@ class QueryLoop:
         a retry hint instead of growing the queue. ``deadline_us`` is the
         client's latency budget: a ticket still queued past it finishes
         ``timed_out`` instead of executing late."""
-        now = self.clock()
-        tid = self._next_tid
-        self._next_tid += 1
-        shape = self.engine.query_shape(query)
-        br = self._breaker.get(shape)
-        if (
-            br is not None and br["open_until"] is not None
-            and now < br["open_until"]
-        ):
-            # shed the poison shape while its breaker is open; the first
-            # ticket admitted after the window passes (or one already
-            # queued) runs as the half-open probe
-            self._count("breaker_shed")
-            self.stats["rejected"] += 1
-            return Ticket(
-                tid=tid, shape=shape, params=dict(params),
-                status="rejected", submitted_us=now,
-                retry_after_us=self._retry_after(now, shape),
+        with span("grf.submit"):
+            now = self.clock()
+            tid = self._next_tid
+            self._next_tid += 1
+            shape = self.engine.query_shape(query)
+            br = self._breaker.get(shape)
+            if (
+                br is not None and br["open_until"] is not None
+                and now < br["open_until"]
+            ):
+                # shed the poison shape while its breaker is open; the first
+                # ticket admitted after the window passes (or one already
+                # queued) runs as the half-open probe
+                self._count("breaker_shed")
+                self.stats["rejected"] += 1
+                return Ticket(
+                    tid=tid, shape=shape, params=dict(params),
+                    status="rejected", submitted_us=now,
+                    retry_after_us=self._retry_after(now, shape),
+                )
+            if self.pending >= self.max_pending:
+                self.stats["rejected"] += 1
+                return Ticket(
+                    tid=tid, shape=shape, params=dict(params),
+                    status="rejected", submitted_us=now,
+                    retry_after_us=self._retry_after(now, shape),
+                )
+            prepared = self.plans.get_or_prepare(
+                shape, lambda: self.engine.prepare(query)
             )
-        if self.pending >= self.max_pending:
-            self.stats["rejected"] += 1
-            return Ticket(
-                tid=tid, shape=shape, params=dict(params),
-                status="rejected", submitted_us=now,
-                retry_after_us=self._retry_after(now, shape),
+            self._prepared[shape] = prepared
+            t = Ticket(
+                tid=tid, shape=shape, params=dict(params), submitted_us=now,
+                deadline_at_us=None if deadline_us is None else now + deadline_us,
             )
-        prepared = self.plans.get_or_prepare(
-            shape, lambda: self.engine.prepare(query)
-        )
-        self._prepared[shape] = prepared
-        t = Ticket(
-            tid=tid, shape=shape, params=dict(params), submitted_us=now,
-            deadline_at_us=None if deadline_us is None else now + deadline_us,
-        )
-        bucket = self._buckets.get(shape)
-        if bucket is None:
-            bucket = self._buckets[shape] = []
-            self._rr.append(shape)
-        if not bucket:
-            self._deadline[shape] = now + self.flush_deadline_us
-        bucket.append(t)
-        self.pending += 1
-        self.stats["admitted"] += 1
-        return t
+            bucket = self._buckets.get(shape)
+            if bucket is None:
+                bucket = self._buckets[shape] = []
+                self._rr.append(shape)
+            if not bucket:
+                self._deadline[shape] = now + self.flush_deadline_us
+            bucket.append(t)
+            self.pending += 1
+            self.stats["admitted"] += 1
+            return t
 
     def _retry_after(self, now: float, shape: Any = None) -> float:
         """Backpressure hint: the earliest queued bucket flushes by its
@@ -331,53 +333,54 @@ class QueryLoop:
                 continue
             prepared = self._prepared[shape]
             for t in batch:
-                if t.deadline_at_us is not None and now >= t.deadline_at_us:
-                    # client budget already blown: don't spend a lane on it
-                    t.status = "timed_out"
-                    t.done_us = self.clock()
-                    self.pending -= 1
-                    self._count("timed_out")
-                    done.append(t)
-                    continue
-                try:
-                    t.result = prepared.bind(**t.params).execute()
-                except TransientFault as e:
-                    self._count("transient_faults")
-                    if t.retries < self.max_retries:
-                        # bounded retry with exponential backoff: the
-                        # ticket stays pending, deferred past its backoff
-                        t.retries += 1
-                        t.not_before_us = now + self.retry_backoff_us * (
-                            2 ** (t.retries - 1)
-                        )
-                        self._buckets[shape].append(t)
-                        self._deadline[shape] = min(
-                            self._deadline.get(shape, _INF), t.not_before_us
-                        )
-                        self._count("retries")
+                with span("grf.ticket"):
+                    if t.deadline_at_us is not None and now >= t.deadline_at_us:
+                        # client budget already blown: don't spend a lane on it
+                        t.status = "timed_out"
+                        t.done_us = self.clock()
+                        self.pending -= 1
+                        self._count("timed_out")
+                        done.append(t)
                         continue
-                    t.error = e
-                    t.status = "failed"
-                    t.done_us = self.clock()
-                    self.pending -= 1
-                    self._count("failed")
-                    self._shape_failure(shape, now)
-                    done.append(t)
-                except Exception as e:  # noqa: BLE001 - per-ticket isolation
-                    t.error = e
-                    t.status = "failed"
-                    t.done_us = self.clock()
-                    self.pending -= 1
-                    self._count("failed")
-                    self._shape_failure(shape, now)
-                    done.append(t)
-                else:
-                    t.status = "done"
-                    t.done_us = self.clock()
-                    self.pending -= 1
-                    self.stats["executed"] += 1
-                    self._shape_success(shape)
-                    done.append(t)
+                    try:
+                        t.result = prepared.bind(**t.params).execute()
+                    except TransientFault as e:
+                        self._count("transient_faults")
+                        if t.retries < self.max_retries:
+                            # bounded retry with exponential backoff: the
+                            # ticket stays pending, deferred past its backoff
+                            t.retries += 1
+                            t.not_before_us = now + self.retry_backoff_us * (
+                                2 ** (t.retries - 1)
+                            )
+                            self._buckets[shape].append(t)
+                            self._deadline[shape] = min(
+                                self._deadline.get(shape, _INF), t.not_before_us
+                            )
+                            self._count("retries")
+                            continue
+                        t.error = e
+                        t.status = "failed"
+                        t.done_us = self.clock()
+                        self.pending -= 1
+                        self._count("failed")
+                        self._shape_failure(shape, now)
+                        done.append(t)
+                    except Exception as e:  # noqa: BLE001 - per-ticket isolation
+                        t.error = e
+                        t.status = "failed"
+                        t.done_us = self.clock()
+                        self.pending -= 1
+                        self._count("failed")
+                        self._shape_failure(shape, now)
+                        done.append(t)
+                    else:
+                        t.status = "done"
+                        t.done_us = self.clock()
+                        self.pending -= 1
+                        self.stats["executed"] += 1
+                        self._shape_success(shape)
+                        done.append(t)
             self.stats["flushes"] += 1
         return done
 

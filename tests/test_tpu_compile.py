@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import grfusion as CELL
 from repro.core import traversal as T
+from repro.core import traversal_engine as TE
 from repro.dist.sharding import TRAVERSAL_AXIS, edge_stream_specs
 from repro.kernels.frontier import shard as FS
 from repro.kernels.frontier.kernel import MSG_DTYPE, frontier_hop
@@ -104,6 +105,17 @@ def test_xla_bfs_compiles_at_twitter_scale(one_chip):
                            block_size=1 << 16)
     ).lower(view, sources).compile()
     assert view.n_vertices == CELL.V and view.n_slots == CELL.E
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_engine_bfs_with_hops_compiles_at_twitter_scale(one_chip):
+    """The sweep ``TraversalEngine`` runs: ``T.bfs`` plus its hop count."""
+    view = _placed(CELL._abstract_view(), one_chip)
+    sources = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled = TE._bfs_xla.lower(
+        view, sources, None, None, sources, max_hops=32, block_size=1 << 16
+    ).compile()
+    assert "jit_bfs" in compiled.as_text().splitlines()[0]
     assert _total_bytes(compiled) < HBM_BYTES
 
 
