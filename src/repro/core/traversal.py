@@ -98,6 +98,13 @@ BFS_STATIC_ARGNAMES = (
     "max_hops", "block_size", "unroll_hops", "state_spec", "dist_dtype"
 )
 
+# A hop whose frontier fires ``m_f`` CSR edges runs frontier-sparse when
+# ``m_f * SPARSE_EDGE_COST`` fits in the padded stream, else dense: the
+# chip's cost of one sparse frontier edge over one dense slot. Measured on
+# one TPU v5e at Graph500 SCALE 21 (2^26 slots): 85 ns an edge against
+# 23 ns a slot, break-even at 3.8 (PERF.md).
+SPARSE_EDGE_COST = 4
+
 
 @functools.partial(jax.jit, static_argnames=BFS_STATIC_ARGNAMES)
 def bfs(
@@ -118,7 +125,8 @@ def bfs(
     With ``target_pos`` the sweep stops as soon as every query lane has
     reached its target (the paper's reachability + LIMIT 1 pattern).
     ``unroll_hops`` replaces the early-exit while loop with a fixed
-    unrolled sweep (dry-run cost accounting; XLA counts loop bodies once).
+    unrolled sweep of dense hops (dry-run cost accounting; XLA counts loop
+    bodies once).
     """
     return bfs_hops(
         view, source_pos, edge_mask_by_row, vertex_mask, target_pos,
@@ -140,12 +148,21 @@ def bfs_hops(
     state_spec=None,
     dist_dtype: str = "int32",
 ):
-    """``bfs`` (unjitted) that also returns the hops the sweep ran: (dist
-    [S, V], hops int32 scalar). ``TraversalEngine`` jits this one and counts
-    the hops without a host sync per query."""
+    """``bfs`` (unjitted) that also counts its hops: (dist [S, V], hops,
+    sparse hops), both int32 scalars. ``TraversalEngine`` jits this one and
+    counts the hops without a host sync per query.
+
+    Each hop picks its form on the device. Dense sweeps every slot of the
+    blocked main + delta stream. Frontier-sparse (top-down, Beamer et al.,
+    SC'12) walks only the frontier's CSR rows in ``block_size`` chunks,
+    then the delta buffer; it runs while the frontier's out-edges times
+    ``SPARSE_EDGE_COST`` fit in the padded stream. Both give the same
+    next frontier, so ``dist`` and the hop count do not depend on the form.
+    """
     V = view.n_vertices
     S = source_pos.shape[0]
     vmask = view.v_valid if vertex_mask is None else (view.v_valid & vertex_mask)
+    vmask_u8 = vmask.astype(jnp.uint8)[None, :]
 
     src_b, dst_b, eid_b, nb = _blocked_coo(view, block_size)
     ecap = 1 if edge_mask_by_row is None else edge_mask_by_row.shape[0]
@@ -153,6 +170,7 @@ def bfs_hops(
     emask_b = (eid_b >= 0) & jnp.take(
         emask_rows, jnp.clip(eid_b, 0, emask_rows.shape[0] - 1)
     )
+    sparse_limit = int(nb * block_size / SPARSE_EDGE_COST)
 
     ddt = jnp.dtype(dist_dtype)
 
@@ -166,7 +184,7 @@ def bfs_hops(
         .at[jnp.arange(S), source_pos]
         .set(1, mode="drop")
     )
-    frontier0 = constrain(frontier0 * vmask.astype(jnp.uint8)[None, :])
+    frontier0 = constrain(frontier0 * vmask_u8)
     dist0 = constrain(jnp.where(frontier0 > 0, 0, -1).astype(ddt))
 
     src_c = jnp.clip(src_b, 0, V - 1)
@@ -184,6 +202,61 @@ def bfs_hops(
             return nxt
         return jax.lax.fori_loop(0, nb, body, jnp.zeros_like(frontier))
 
+    # the delta buffer, swept by the sparse form after the CSR (main only)
+    d_ok = view.delta_valid & view.gather_edge_mask(emask_rows, view.delta_eid)
+    d_src = jnp.clip(view.delta_src, 0, V - 1)
+    d_dst = jnp.where(d_ok, view.delta_dst, V)
+    chunk = block_size
+    k = jnp.arange(chunk, dtype=jnp.int32)
+
+    def expand_sparse(frontier, fired, m_f):
+        """Top-down over the CSR rows of the frontier vertices, whose
+        ``fired`` (fan-out) edges sum to ``m_f``: cost in chunks of ``m_f``.
+
+        The vertices with edges are listed in order; edge ``g`` of the
+        frontier (0 <= g < m_f) is the ``g - first[i]``-th out-edge of list
+        entry ``i``. A chunk finds each slot's entry by marking where
+        entries start and taking the running max, with no search per slot.
+        """
+        has = fired > 0
+        at = jnp.where(has, jnp.cumsum(has.astype(jnp.int32)) - 1, V + chunk)
+        first = jnp.cumsum(fired) - fired
+        # padded by a chunk of INT_MAX so a chunk's slice of starts stays whole
+        l_first = jnp.full((V + chunk,), INT_MAX, jnp.int32).at[at].set(first, mode="drop")
+        l_base = jnp.zeros((V,), jnp.int32).at[at].set(
+            view.out_offsets[:-1] - first, mode="drop")
+        if S > 1:  # one lane: every listed vertex is that lane's frontier
+            l_vert = jnp.zeros((V,), jnp.int32).at[at].set(
+                jnp.arange(V, dtype=jnp.int32), mode="drop")
+
+        def body(c, carry):
+            with jax.named_scope("grf.bfs.chunk"):
+                nxt, i0 = carry  # i0: the entry holding the chunk's first edge
+                g0 = c * chunk
+                starts = jax.lax.dynamic_slice(l_first, (i0 + 1,), (chunk,)) - g0
+                mark = jnp.full((chunk,), -1, jnp.int32).at[starts].set(
+                    i0 + 1 + k, mode="drop")
+                entry = jax.lax.cummax(jnp.maximum(mark, i0))
+                ok = g0 + k < m_f
+                slot = jnp.where(ok, jnp.take(l_base, entry) + g0 + k, 0)
+                dst = jnp.take(view.out_dst, slot)
+                ok = ok & (dst < V) & view.gather_edge_mask(
+                    emask_rows, jnp.take(view.out_eid, slot))
+                if S > 1:
+                    msgs = jnp.take(frontier, jnp.take(l_vert, entry), axis=1)
+                    msgs = msgs * ok.astype(jnp.uint8)
+                else:
+                    msgs = ok.astype(jnp.uint8)[None, :]
+                nxt = nxt.at[:, jnp.where(ok, dst, V)].max(msgs, mode="drop")
+                return nxt, entry[-1]
+
+        n_chunks = (m_f + chunk - 1) // chunk
+        nxt, _ = jax.lax.fori_loop(
+            0, n_chunks, body, (jnp.zeros_like(frontier), jnp.int32(0))
+        )
+        msgs = jnp.take(frontier, d_src, axis=1) * d_ok.astype(jnp.uint8)
+        return nxt.at[:, d_dst].max(msgs, mode="drop")
+
     def targets_done(dist):
         if target_pos is None:
             return jnp.asarray(False)
@@ -193,26 +266,33 @@ def bfs_hops(
         return jnp.all(found)
 
     def cond(state):
-        frontier, _, dist, hop = state
+        frontier, _, dist, hop, _ = state
         return (hop < max_hops) & jnp.any(frontier > 0) & ~targets_done(dist)
 
     def step(state):
         with jax.named_scope("grf.bfs.hop"):
-            frontier, visited, dist, hop = state
-            nxt = expand(frontier)
-            nxt = constrain(nxt * (1 - visited) * vmask.astype(jnp.uint8)[None, :])
+            frontier, visited, dist, hop, n_sparse = state
+            if unroll_hops:
+                nxt, sparse = expand(frontier), jnp.asarray(False)
+            else:
+                fired = jnp.where(jnp.any(frontier > 0, axis=0), view.fan_out, 0)
+                m_f = jnp.sum(fired)
+                sparse = m_f <= sparse_limit
+                nxt = jax.lax.cond(
+                    sparse, lambda f: expand_sparse(f, fired, m_f), expand, frontier
+                )
+            nxt = constrain(nxt * (1 - visited) * vmask_u8)
             dist = constrain(jnp.where(nxt > 0, (hop + 1).astype(ddt), dist))
-            return nxt, constrain(visited | nxt), dist, hop + 1
+            return (nxt, constrain(visited | nxt), dist, hop + 1,
+                    n_sparse + sparse.astype(jnp.int32))
 
+    state = (frontier0, frontier0, dist0, jnp.int32(0), jnp.int32(0))
     if unroll_hops:
-        state = (frontier0, frontier0, dist0, jnp.int32(0))
         for _ in range(max_hops):
             state = step(state)
-        return state[2], state[3]
-    _, _, dist, hop = jax.lax.while_loop(
-        cond, step, (frontier0, frontier0, dist0, jnp.int32(0))
-    )
-    return dist, hop
+    else:
+        state = jax.lax.while_loop(cond, step, state)
+    return state[2], state[3], state[4]
 
 
 # --------------------------------------------------------------------------

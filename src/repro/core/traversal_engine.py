@@ -283,8 +283,10 @@ class TraversalEngine:
         self.lane_width = lane_width
         self.max_lanes = max_lanes  # widest single [S, V] sweep flush builds
         self._stats = collections.Counter()
-        # xla_coo hops, summed on the device until ``stats`` reads them
+        # xla_coo hops (all, and the frontier-sparse ones), summed on the
+        # device until ``stats`` reads them
         self._hops = jnp.zeros((), jnp.int32)
+        self._sparse_hops = jnp.zeros((), jnp.int32)
         # graph -> (topology key, avg_fan_out as a host float)
         self._fan_out: Dict[str, Tuple[Tuple, float]] = {}
         self._packs: "collections.OrderedDict" = collections.OrderedDict()
@@ -303,9 +305,13 @@ class TraversalEngine:
     @property
     def stats(self) -> collections.Counter:
         """Per-engine event counts merged with the shared trace counters.
-        ``hops_xla_coo`` sums the hops every ``xla_coo`` BFS sweep ran."""
-        hops = collections.Counter(hops_xla_coo=int(jax.device_get(self._hops)))
-        return self._stats + hops + _TRACE_COUNTS + FS.TRACE_COUNTS
+        ``hops_xla_coo`` sums the hops every ``xla_coo`` BFS sweep ran,
+        ``hops_xla_coo_sparse`` those of them that ran frontier-sparse."""
+        hops, sparse = jax.device_get((self._hops, self._sparse_hops))
+        counted = collections.Counter(
+            hops_xla_coo=int(hops), hops_xla_coo_sparse=int(sparse)
+        )
+        return self._stats + counted + _TRACE_COUNTS + FS.TRACE_COUNTS
 
     def fan_out(self, view: GraphView, graph: Optional[str] = None) -> float:
         """``view.avg_fan_out`` as a host float, read once per topology
@@ -599,11 +605,12 @@ class TraversalEngine:
         """One BFS on one specific backend (the failover unit)."""
         faults.check(SITE_DISPATCH[b])
         if b == "xla_coo":
-            dist, hops = _bfs_xla(
+            dist, hops, sparse = _bfs_xla(
                 view, source_pos, edge_mask_by_row, vertex_mask,
                 target_pos, max_hops=max_hops, block_size=self._block_for(view),
             )
             self._hops = self._hops + hops
+            self._sparse_hops = self._sparse_hops + sparse
             return dist
         if b == "pallas_frontier":
             ps, pe, ldst = self.get_pack(view, graph)

@@ -222,6 +222,6 @@ def test_engine_sweep_keeps_the_module_name_and_distances():
     src = jnp.asarray([0, 2], jnp.int32)
     text = TE._bfs_xla.lower(view, src, max_hops=32, block_size=1024).as_text()
     assert "jit_bfs" in text.splitlines()[0]  # the benchmark reads jit_bfs
-    dist, hops = TE._bfs_xla(view, src, max_hops=32, block_size=1024)
+    dist, hops, _ = TE._bfs_xla(view, src, max_hops=32, block_size=1024)
     assert np.array_equal(np.asarray(dist), np.asarray(T.bfs(view, src, max_hops=32)))
     assert int(hops) == N  # lane 0 reaches vertex 7 on hop 7; hop 8 finds none

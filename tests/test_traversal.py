@@ -1,13 +1,17 @@
 """Traversal physical operators vs. independent oracles (hypothesis)."""
 import heapq
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _prop import given, settings, st
 
+from differential.test_backends import FAMILIES, _sources, build_case
 from repro.core import traversal as T
 from repro.core.graphview import build_graph_view
 from repro.core.table import Table
+from repro.core.traversal_engine import TraversalEngine
 
 
 def make_view(n, src, dst, extra_cols=None, directed=True):
@@ -165,3 +169,116 @@ def test_bfs_respects_edge_and_vertex_masks():
     d2 = np.asarray(T.bfs(view, jnp.array([0], jnp.int32),
                           edge_mask_by_row=emask, vertex_mask=vmask, max_hops=4))[0]
     assert d2[2] == -1  # vertex 1 excluded => unreachable
+
+
+# --------------------------------------------------------------------------
+# frontier-sparse hops: the same distances and hop counts as the dense sweep
+# --------------------------------------------------------------------------
+_bfs_hops = jax.jit(T.bfs_hops, static_argnames=T.BFS_STATIC_ARGNAMES)
+
+
+def _reference(view, srcs, emask=None, vmask=None, tgt=None, max_hops=24):
+    return TraversalEngine._bfs_reference(view, srcs, emask, vmask, tgt,
+                                          max_hops=max_hops)
+
+
+def _check_forms(view, srcs, emask=None, vmask=None, tgt=None, *, block,
+                 max_hops=24):
+    """dist and hops of the while loop (sparse and dense hops mixed) against
+    the numpy reference on the active lanes and the dense-only unrolled
+    sweep on every lane; returns (reference dist, hops, sparse hops)."""
+    dist, hops, sparse = _bfs_hops(view, srcs, emask, vmask, tgt,
+                                   max_hops=max_hops, block_size=block)
+    dist = np.asarray(dist)
+    ref = _reference(view, srcs, emask, vmask, tgt, max_hops)
+    active = np.asarray(srcs) >= 0  # the sweeps start lane -1 at vertex V - 1
+    assert np.array_equal(dist[active], ref[active])
+    if tgt is None:  # the unrolled sweep has no early exit
+        dense = T.bfs(view, srcs, emask, vmask, max_hops=max_hops,
+                      block_size=1024, unroll_hops=True)
+        assert np.array_equal(dist, np.asarray(dense))
+    return ref, int(hops), int(sparse)
+
+
+@pytest.mark.parametrize("block", [16, 1024])
+@pytest.mark.parametrize("lanes", [1, 3])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_sparse_hops_match_reference_and_dense(family, lanes, block):
+    # block 16: chunks split frontier rows and forms mix; 1024: all sparse
+    view, _, emask = build_case(family, 0)
+    srcs = _sources(view, 0, s=lanes)
+    ref, hops, sparse = _check_forms(view, srcs, emask, block=block)
+    assert hops == min(24, int(ref.max()) + 1)  # the last hop finds nothing
+    assert 0 <= sparse <= hops
+    if block == 1024:
+        assert sparse == hops
+
+
+def _hub_view(k=200, delta_capacity=16):
+    """0 -> 1 -> {2..k+1} (the hub), 2 -> k+2 -> k+3: hop 2 fires k edges,
+    more than a quarter of the stream, every other hop at most one."""
+    n = k + 4
+    src = [0] + [1] * k + [2, k + 2]
+    dst = [1] + list(range(2, k + 2)) + [k + 2, k + 3]
+    vt = Table.create("V", {"vid": np.arange(n, dtype=np.int32)})
+    et = Table.create("E", {"src": np.asarray(src, np.int32),
+                            "dst": np.asarray(dst, np.int32)})
+    view = build_graph_view("G", vt, et, v_id="vid", e_src="src", e_dst="dst",
+                            delta_capacity=delta_capacity)
+    return view, et
+
+
+@pytest.mark.parametrize("lanes, dense", [
+    ([0], 1),  # hop 2 expands the hub
+    ([0, -1, 1], 2),  # the hub's lane expands it on hop 1, lane 0 on hop 2
+])
+def test_hub_runs_sparse_then_dense_then_sparse(lanes, dense):
+    view, _ = _hub_view()
+    srcs = jnp.asarray(lanes, jnp.int32)
+    ref, hops, sparse = _check_forms(view, srcs, block=16)
+    assert hops == 5 and ref[0, 203] == 4
+    assert 0 < sparse < hops
+    assert sparse == hops - dense
+
+
+def _hub_case(case):
+    """(view, sources, edge mask, vertex mask, targets) of the hub graph with
+    one more feature: delta edges, a tombstone, a mask or a target."""
+    view, et = _hub_view()
+    srcs = jnp.asarray([0], jnp.int32)
+    emask = vmask = tgt = None
+    if case == "delta":  # k+3 -> k+4 -> k+5 arrive through the delta buffer
+        view = build_graph_view(
+            "G", Table.create("V", {"vid": np.arange(210, dtype=np.int32)}),
+            et, v_id="vid", e_src="src", e_dst="dst", delta_capacity=16)
+        view, dropped = view.insert_delta(
+            jnp.asarray([203, 204], jnp.int32), jnp.asarray([204, 205], jnp.int32),
+            jnp.asarray([0, 1], jnp.int32), jnp.asarray([True, True]))
+        assert int(dropped) == 0
+    elif case == "tombstone":  # row 201 (2 -> k+2) deleted after the build
+        emask = et.delete_rows(jnp.asarray([201], jnp.int32)).valid
+    elif case == "edge_mask":  # rows 2, 4, .., 200: 1 -> 3, 5, .., 201
+        m = np.ones(et.capacity, bool)
+        m[2:201:2] = False
+        emask = jnp.asarray(m)
+    elif case == "vertex_mask":  # vertex 2 excluded: k+2 unreachable
+        vmask = jnp.asarray(np.arange(view.n_vertices) != 2)
+    elif case == "target":  # stops on hop 3, before the tail's hops
+        tgt = jnp.asarray([202], jnp.int32)
+    return view, srcs, emask, vmask, tgt
+
+
+@pytest.mark.parametrize("case, hops, reached", [
+    ("delta", 7, {205: 6}),
+    ("tombstone", 3, {202: -1, 203: -1, 2: 2}),
+    ("edge_mask", 5, {3: -1, 4: 2, 203: 4}),
+    ("vertex_mask", 3, {2: -1, 202: -1, 3: 2}),
+    ("target", 3, {202: 3, 203: -1}),
+])
+def test_sparse_hops_with_delta_masks_and_targets(case, hops, reached):
+    view, srcs, emask, vmask, tgt = _hub_case(case)
+    ref, got_hops, sparse = _check_forms(view, srcs, emask, vmask, tgt, block=16)
+    assert got_hops == hops
+    assert 0 < sparse < hops  # the hub's hop stays dense
+    for v, d in reached.items():
+        assert ref[0, v] == d

@@ -1,4 +1,5 @@
 """TraversalEngine unit tests: backend policy, per-query knob, serving path."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -227,3 +228,14 @@ def test_submit_sssp_admission():
     te.flush(max_iters=16)
     assert h1.result["reachable"] and h1.result["distance"] == pytest.approx(18.0)
     assert not h2.result["reachable"]
+
+
+def test_sparse_hop_counter_sums_on_device_and_reads_with_stats():
+    view = _chain_view()  # 11 edges: every hop fires at most one, so sparse
+    te = TraversalEngine(default_backend="xla_coo")
+    with jax.transfer_guard_device_to_host("disallow"):
+        for _ in range(3):
+            te.bfs(view, jnp.asarray([0], jnp.int32), max_hops=2)
+    assert isinstance(te._sparse_hops, jax.Array) and not te.events
+    stats = te.stats
+    assert stats["hops_xla_coo_sparse"] == stats["hops_xla_coo"] == 6
