@@ -6,7 +6,7 @@ Spec: ``{"kind": "open", "rate_per_s": r}``. One stream of
 gaps are the stratified quantiles of an exponential law in one fixed
 order, scaled to fill the window exactly. The order does not follow the
 seed: where the short gaps cluster sets the tail, and a seed that moved
-them would move ``p95_ms`` by more than any bound can hold (PERF.md).
+them would move the tail by more than any bound can hold (PERF.md).
 
 The window's requests are those due before ``--seconds``; one with no
 answer ``grace_s`` past the close never came.

@@ -5,7 +5,7 @@ Reduced from the profiler trace (``bench/trace.py``): 1 - the union of the
 TPU op intervals over the ``bench.window`` span.
 """
 LAYER = "device"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 
 
 def read(window):

@@ -3,7 +3,7 @@ of the ``jit_enumerate_paths`` XLA module (``core/traversal.py``
 ``enumerate_paths``) in the traced window, over the queries answered in it.
 """
 LAYER = "path enumeration"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 MODULE = "jit_enumerate_paths"
 
 

@@ -5,7 +5,7 @@ executed in the window. Every neighbourhood ticket takes one path, so the
 reading is the same integer on every seed.
 """
 LAYER = "plan, bind and executor"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 PREFIX = "events.host_sync."
 
 

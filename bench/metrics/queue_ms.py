@@ -2,7 +2,7 @@
 of the ``QueryLoop.pump`` that served it (host clock).
 """
 LAYER = "serve loop"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 
 
 def read(window):

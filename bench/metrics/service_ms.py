@@ -3,7 +3,7 @@ binding, the executor and the device work it waits for, over the pumps that
 served a ticket (the benchmark's ``bench.pump`` span, on the host clock).
 """
 LAYER = "plan, bind and executor"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 
 
 def read(window):

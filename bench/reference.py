@@ -50,16 +50,17 @@ def hop_distances(adj: Adjacency, n_vertices: int, roots, max_hops: int) -> np.n
     in that root's frontier. ``adj`` holds each vertex's in-neighbours (for
     an undirected graph, its neighbours)."""
     roots = np.asarray(roots, np.int64)
-    dist = np.full((len(roots), n_vertices), -1, np.int8)
+    dist = np.full((n_vertices, len(roots)), -1, np.int8)  # vertex-major
     has_in = np.flatnonzero(np.diff(adj.offsets) > 0)
     starts = adj.offsets[has_in]
     for lo in range(0, len(roots), 64):
         chunk = roots[lo:lo + 64]
+        word = dist[:, lo:lo + len(chunk)]
         lanes = np.uint64(1) << np.arange(len(chunk), dtype=np.uint64)
         frontier = np.zeros(n_vertices, np.uint64)
         np.bitwise_or.at(frontier, chunk, lanes)
         seen = frontier.copy()
-        dist[lo + np.arange(len(chunk)), chunk] = 0
+        word[chunk, np.arange(len(chunk))] = 0
         for hop in range(1, max_hops + 1):
             if not frontier.any():
                 break
@@ -69,10 +70,11 @@ def hop_distances(adj: Adjacency, n_vertices: int, roots, max_hops: int) -> np.n
             frontier = pulled & ~seen
             seen |= frontier
             hit = np.flatnonzero(frontier)
-            bits = (frontier[hit, None] >> np.arange(len(chunk), dtype=np.uint64)) & 1
-            lane, col = np.nonzero(bits.T.astype(bool))
-            dist[lo + lane, hit[col]] = hop
-    return dist
+            # lane i of a frontier word is bit i: byte i // 8, bit i % 8
+            bits = np.unpackbits(frontier[hit].astype("<u8").view(np.uint8).reshape(-1, 8),
+                                 axis=1, count=len(chunk), bitorder="little")
+            word[hit] = np.where(bits.astype(bool), np.int8(hop), word[hit])
+    return np.ascontiguousarray(dist.T)
 
 
 def same(kind, q, result, expected: np.ndarray) -> bool:

@@ -43,7 +43,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Dict, List  # noqa: E402
+from typing import Any, Dict  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 for _p in (str(ROOT / "src"), str(ROOT)):
@@ -57,7 +57,7 @@ from bench import reference, traffic  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
 from bench.registry import Cell, Registry  # noqa: E402
 from bench.trace import read_xplane, summarize  # noqa: E402
-from bench.window import Window  # noqa: E402
+from bench.window import Window, nearest_rank  # noqa: E402
 
 GRACE_S = 60.0  # how long past the window an answer is awaited
 COMPILE_EVENTS = (
@@ -138,23 +138,16 @@ def check_answers(cell: Cell, reg: Registry, dep, win: Window):
 
 
 # ---------------------------------------------------------------- metrics
-def nearest_rank(values: List[float], pct: float) -> float:
-    s = sorted(values)
-    return s[max(math.ceil(pct / 100.0 * len(s)) - 1, 0)]
-
-
 def end_to_end(cell: Cell, win: Window, setup_s: float, peak: int) -> Dict[str, Dict]:
-    """The cell's end-to-end metrics. A tail counts every request of the
-    window, timed from when it was due; a failed one misses any limit. A
-    rate counts the answered, undegraded, complete requests over the
-    window's whole span."""
-    never = win.t_close + GRACE_S
-    lat = [((r.done if r.ok else max(never, r.done or never)) - r.due) * 1e3
-           for r in win.in_window]
+    """The cell's end-to-end metrics. A latency percentile counts every
+    request of the window, timed from when it was due; a failed one misses
+    any limit. A rate counts the answered, undegraded, complete requests
+    over the window's whole span."""
+    lat = win.latencies_ms()
     values = {
         "setup_s": setup_s,
         "peak_hbm_gib": peak / 2 ** 30,
-        "p95_ms": nearest_rank(lat, 95.0) if lat else math.nan,
+        "p50_ms": nearest_rank(lat, 50.0) if lat else math.nan,
         "queries_per_s": sum(1 for r in win.in_window if r.ok) / (win.t_close - win.t0),
     }
     units = {m["name"]: m["unit"] for m in cell.end_to_end}
@@ -256,7 +249,7 @@ def _run(args, reg, cell, devices, tracing, compiles) -> int:
     in_window = wl.arrivals.in_window(drive)
     win = Window(drive=drive, in_window=in_window,
                  missing=wl.arrivals.missing(drive, in_window),
-                 counters={k: v for k, v in delta.items() if v})
+                 counters={k: v for k, v in delta.items() if v}, grace_s=GRACE_S)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
     del eng, loop
@@ -270,6 +263,10 @@ def _run(args, reg, cell, devices, tracing, compiles) -> int:
     if late:
         log(f"generator lateness (ms): max={max(late) * 1e3:.3f} "
             f"p95={nearest_rank(late, 95.0) * 1e3:.3f}")
+    lat = win.latencies_ms()
+    if lat:
+        log("latency from due (ms): " + " ".join(
+            f"p{p}={nearest_rank(lat, p):.3f}" for p in (50, 95, 99)) + f" n={len(lat)}")
     if tracing:
         try:
             paths = sorted(Path(trace_dir).rglob("*.xplane.pb"))

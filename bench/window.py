@@ -2,10 +2,16 @@
 drives the serving loop, and what the check and the metric readers read."""
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 from bench.trace import Summary
+
+
+def nearest_rank(values: List[float], pct: float) -> float:
+    s = sorted(values)
+    return s[max(math.ceil(pct / 100.0 * len(s)) - 1, 0)]
 
 
 @dataclass
@@ -60,6 +66,7 @@ class Window:
     missing: int  # requests of the window that never got an answer
     counters: Dict[str, int] = field(default_factory=dict)
     trace: Optional[Summary] = None
+    grace_s: float = 60.0  # how long past the close an answer was awaited
 
     @property
     def t0(self) -> float:
@@ -76,6 +83,14 @@ class Window:
     @property
     def pumps(self) -> List[Pump]:
         return self.drive.pumps
+
+    def latencies_ms(self) -> List[float]:
+        """Every request of the window, timed from when it was due; one
+        that failed, or never came, counts as answered at the grace limit
+        or later, so it misses any latency limit."""
+        never = self.t_close + self.grace_s
+        return [((r.done if r.ok else max(never, r.done or never)) - r.due) * 1e3
+                for r in self.in_window]
 
     @property
     def finished(self) -> List[Request]:

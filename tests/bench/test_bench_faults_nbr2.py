@@ -16,7 +16,7 @@ def test_clean_run_is_correct(root, capsys):
     assert rc == 0 and out["correct"] is True
     assert out["failed"] == 0 and out["attempted"] == 50
     assert out["compared"]["wrong_answers"] == {"value": 0, "limit": 0}
-    assert set(out["metrics"]) == {"setup_s", "p95_ms", "peak_hbm_gib"}
+    assert set(out["metrics"]) == {"setup_s", "p50_ms", "peak_hbm_gib"}
     assert list(out)[-1] == "compared"
 
 

@@ -77,7 +77,7 @@ def test_metric_files_name_their_layer_and_end_to_end_metric(spec):
 NEW_FILES = {
     "metrics/answered_per_pump.py": '''
 LAYER = "serve loop"
-MOVES = "p95_ms"
+MOVES = "p50_ms"
 
 
 def read(window):
