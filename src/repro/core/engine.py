@@ -184,6 +184,16 @@ class GRFusion:
         # concurrent clients plan each shape once and bind() per request
         self.plan_cache = PreparedPlanCache()
         self._serving_loop = None
+        # table -> its numeric columns that have held a negative value,
+        # noted where data enters; a shortest path weighed by one of them
+        # is refused (its rounds need non-negative weights to converge)
+        self.negative_columns: Dict[str, set] = collections.defaultdict(set)
+
+    def _note_negatives(self, table: str, cols: Mapping[str, np.ndarray]) -> None:
+        for k, v in cols.items():
+            v = np.asarray(v)
+            if v.dtype.kind in "if" and v.size and np.min(v) < 0:
+                self.negative_columns[table].add(k)
 
     # ------------------------------------------------------------- catalog
     def create_table(self, name: str, data: Mapping[str, np.ndarray], capacity=None) -> Table:
@@ -196,6 +206,8 @@ class GRFusion:
             else:
                 enc[k] = v
         t = Table.create(name, enc, capacity)
+        self.negative_columns.pop(name, None)
+        self._note_negatives(name, enc)
         self.tables[name] = t
         self.epochs.bump(table_key(name))
         return t
@@ -374,6 +386,7 @@ class GRFusion:
                 enc_rows[k], _ = self._encode_column(table_name, k, v)
             else:
                 enc_rows[k] = v
+        self._note_negatives(table_name, enc_rows)
         prev_used = t.used
         prev_epoch = self.table_epoch(table_name)
         t2, slots, overflow = t.insert(enc_rows)
@@ -504,6 +517,7 @@ class GRFusion:
             encode=lambda c, v: self.encode_value(table_name, c, v),
         )
         value = self.encode_value(table_name, col, value)
+        self._note_negatives(table_name, {col: np.asarray(value).reshape(-1)})
         t2 = t.update(mask & t.valid, col, value)
 
         def table_of(name: str) -> Table:

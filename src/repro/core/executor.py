@@ -541,6 +541,11 @@ class PathScanExec(ExecNode):
         elif spec.physical in ("sssp", "bfs_path"):
             if spec.physical == "sssp":
                 wcol = vb.e_attrs.get(spec.sp_weight_attr, spec.sp_weight_attr)
+                if wcol in eng.negative_columns.get(vb.edge_table, ()):
+                    raise ValueError(
+                        f"shortest-path weight {vb.edge_table}.{wcol} holds a "
+                        "negative value; shortest paths need non-negative weights"
+                    )
                 w = et.col(wcol).astype(jnp.float32)
             else:
                 w = jnp.ones((et.capacity,), jnp.float32)
@@ -548,7 +553,7 @@ class PathScanExec(ExecNode):
                 dist, parent = eng.traversal.sssp(
                     view, start_pos, w,
                     edge_mask_by_row=uniform_mask, vertex_mask=gvmask,
-                    max_iters=64, backend=backend, graph=spec.graph,
+                    backend=backend, graph=spec.graph,
                 )
                 ctx.note_degraded(eng.traversal.consume_degraded())
             with span("grf.path.to_batch"):

@@ -91,6 +91,54 @@ def _full_edge_mask(view: GraphView, edge_mask_by_row, edge_table_cap: int):
     return edge_mask_by_row
 
 
+def _walk_rows(view: GraphView, fired, m, emask_rows, relax, acc, *,
+               chunk: int, vertex: bool, scope: str):
+    """Fold the CSR rows of the vertices with ``fired`` (their fan-out, or
+    0) > 0, ``m`` edges in all, into ``acc`` in ``chunk``-slot steps: the
+    cost is in chunks of ``m``, not of the stream. ``relax(acc, vert, eid,
+    dst, ok)`` folds one chunk: per slot its source vertex (None unless
+    ``vertex``), edge row, destination, and whether it is a live slot (in
+    the walk, ``dst < V``, passing ``emask_rows``).
+
+    The vertices with edges are listed in order; edge ``g`` of the walk
+    (0 <= g < m) is the ``g - first[i]``-th out-edge of list entry ``i``. A
+    chunk finds each slot's entry by marking where entries start and taking
+    the running max, with no search per slot.
+    """
+    V = view.n_vertices
+    k = jnp.arange(chunk, dtype=jnp.int32)
+    has = fired > 0
+    at = jnp.where(has, jnp.cumsum(has.astype(jnp.int32)) - 1, V + chunk)
+    first = jnp.cumsum(fired) - fired
+    # padded by a chunk of INT_MAX so a chunk's slice of starts stays whole
+    l_first = jnp.full((V + chunk,), INT_MAX, jnp.int32).at[at].set(first, mode="drop")
+    l_base = jnp.zeros((V,), jnp.int32).at[at].set(
+        view.out_offsets[:-1] - first, mode="drop")
+    if vertex:
+        l_vert = jnp.zeros((V,), jnp.int32).at[at].set(
+            jnp.arange(V, dtype=jnp.int32), mode="drop")
+
+    def body(c, carry):
+        with jax.named_scope(scope):
+            acc, i0 = carry  # i0: the entry holding the chunk's first edge
+            g0 = c * chunk
+            starts = jax.lax.dynamic_slice(l_first, (i0 + 1,), (chunk,)) - g0
+            mark = jnp.full((chunk,), -1, jnp.int32).at[starts].set(
+                i0 + 1 + k, mode="drop")
+            entry = jax.lax.cummax(jnp.maximum(mark, i0))
+            ok = g0 + k < m
+            slot = jnp.where(ok, jnp.take(l_base, entry) + g0 + k, 0)
+            dst = jnp.take(view.out_dst, slot)
+            eid = jnp.take(view.out_eid, slot)
+            ok = ok & (dst < V) & view.gather_edge_mask(emask_rows, eid)
+            vert = jnp.take(l_vert, entry) if vertex else None
+            return relax(acc, vert, eid, dst, ok), entry[-1]
+
+    n_chunks = (m + chunk - 1) // chunk
+    acc, _ = jax.lax.fori_loop(0, n_chunks, body, (acc, jnp.int32(0)))
+    return acc
+
+
 # --------------------------------------------------------------------------
 # BFScan — multi-source frontier BFS
 # --------------------------------------------------------------------------
@@ -102,7 +150,8 @@ BFS_STATIC_ARGNAMES = (
 # ``m_f * SPARSE_EDGE_COST`` fits in the padded stream, else dense: the
 # chip's cost of one sparse frontier edge over one dense slot. Measured on
 # one TPU v5e at Graph500 SCALE 21 (2^26 slots): 85 ns an edge against
-# 23 ns a slot, break-even at 3.8 (PERF.md).
+# 23 ns a slot, break-even at 3.8; for an SSSP round at SCALE 18 (2^23
+# slots): 62 ns an edge against 14 ns a slot, break-even at 4.6 (PERF.md).
 SPARSE_EDGE_COST = 4
 
 
@@ -206,54 +255,20 @@ def bfs_hops(
     d_ok = view.delta_valid & view.gather_edge_mask(emask_rows, view.delta_eid)
     d_src = jnp.clip(view.delta_src, 0, V - 1)
     d_dst = jnp.where(d_ok, view.delta_dst, V)
-    chunk = block_size
-    k = jnp.arange(chunk, dtype=jnp.int32)
 
     def expand_sparse(frontier, fired, m_f):
         """Top-down over the CSR rows of the frontier vertices, whose
-        ``fired`` (fan-out) edges sum to ``m_f``: cost in chunks of ``m_f``.
+        ``fired`` (fan-out) edges sum to ``m_f``, then the delta buffer."""
+        def relax(nxt, vert, eid, dst, ok):
+            if S > 1:
+                msgs = jnp.take(frontier, vert, axis=1) * ok.astype(jnp.uint8)
+            else:  # one lane: every listed vertex is that lane's frontier
+                msgs = ok.astype(jnp.uint8)[None, :]
+            return nxt.at[:, jnp.where(ok, dst, V)].max(msgs, mode="drop")
 
-        The vertices with edges are listed in order; edge ``g`` of the
-        frontier (0 <= g < m_f) is the ``g - first[i]``-th out-edge of list
-        entry ``i``. A chunk finds each slot's entry by marking where
-        entries start and taking the running max, with no search per slot.
-        """
-        has = fired > 0
-        at = jnp.where(has, jnp.cumsum(has.astype(jnp.int32)) - 1, V + chunk)
-        first = jnp.cumsum(fired) - fired
-        # padded by a chunk of INT_MAX so a chunk's slice of starts stays whole
-        l_first = jnp.full((V + chunk,), INT_MAX, jnp.int32).at[at].set(first, mode="drop")
-        l_base = jnp.zeros((V,), jnp.int32).at[at].set(
-            view.out_offsets[:-1] - first, mode="drop")
-        if S > 1:  # one lane: every listed vertex is that lane's frontier
-            l_vert = jnp.zeros((V,), jnp.int32).at[at].set(
-                jnp.arange(V, dtype=jnp.int32), mode="drop")
-
-        def body(c, carry):
-            with jax.named_scope("grf.bfs.chunk"):
-                nxt, i0 = carry  # i0: the entry holding the chunk's first edge
-                g0 = c * chunk
-                starts = jax.lax.dynamic_slice(l_first, (i0 + 1,), (chunk,)) - g0
-                mark = jnp.full((chunk,), -1, jnp.int32).at[starts].set(
-                    i0 + 1 + k, mode="drop")
-                entry = jax.lax.cummax(jnp.maximum(mark, i0))
-                ok = g0 + k < m_f
-                slot = jnp.where(ok, jnp.take(l_base, entry) + g0 + k, 0)
-                dst = jnp.take(view.out_dst, slot)
-                ok = ok & (dst < V) & view.gather_edge_mask(
-                    emask_rows, jnp.take(view.out_eid, slot))
-                if S > 1:
-                    msgs = jnp.take(frontier, jnp.take(l_vert, entry), axis=1)
-                    msgs = msgs * ok.astype(jnp.uint8)
-                else:
-                    msgs = ok.astype(jnp.uint8)[None, :]
-                nxt = nxt.at[:, jnp.where(ok, dst, V)].max(msgs, mode="drop")
-                return nxt, entry[-1]
-
-        n_chunks = (m_f + chunk - 1) // chunk
-        nxt, _ = jax.lax.fori_loop(
-            0, n_chunks, body, (jnp.zeros_like(frontier), jnp.int32(0))
-        )
+        nxt = _walk_rows(view, fired, m_f, emask_rows, relax,
+                         jnp.zeros_like(frontier), chunk=block_size,
+                         vertex=S > 1, scope="grf.bfs.chunk")
         msgs = jnp.take(frontier, d_src, axis=1) * d_ok.astype(jnp.uint8)
         return nxt.at[:, d_dst].max(msgs, mode="drop")
 
@@ -298,18 +313,17 @@ def bfs_hops(
 # --------------------------------------------------------------------------
 # SPScan — frontier Bellman-Ford with parent extraction
 # --------------------------------------------------------------------------
-SSSP_STATIC_ARGNAMES = ("max_iters", "block_size")
+SSSP_STATIC_ARGNAMES = ("block_size",)
 
 
 @functools.partial(jax.jit, static_argnames=SSSP_STATIC_ARGNAMES)
 def sssp(
     view: GraphView,
-    source_pos: jnp.ndarray,  # int32 [S]
+    source_pos: jnp.ndarray,  # int32 [S]; -1 = inactive query lane
     weight_by_row: jnp.ndarray,  # f32 [edge_cap] (non-negative)
     edge_mask_by_row: jnp.ndarray | None = None,
     vertex_mask: jnp.ndarray | None = None,
     *,
-    max_iters: int = 64,
     block_size: int = 1 << 16,
 ):
     """Shortest-path distances + parent edge slots.
@@ -317,6 +331,46 @@ def sssp(
     Returns (dist f32 [S, V], parent_slot int32 [S, V]) where parent_slot
     indexes the padded COO stream (-1 = none / source). Equivalent to the
     paper's Dijkstra SPScan for non-negative weights.
+    """
+    return sssp_rounds(
+        view, source_pos, weight_by_row, edge_mask_by_row, vertex_mask,
+        block_size=block_size,
+    )[:2]
+
+
+def round_bound(n_vertices: int) -> int:
+    """Rounds an SSSP loop may run: ``V``. With non-negative weights at
+    most ``V - 1`` rounds lower a distance, and one more finds that none
+    falls, so the bound never stops a loop early."""
+    return max(n_vertices, 1)
+
+
+def sssp_rounds(
+    view: GraphView,
+    source_pos: jnp.ndarray,
+    weight_by_row: jnp.ndarray,
+    edge_mask_by_row: jnp.ndarray | None = None,
+    vertex_mask: jnp.ndarray | None = None,
+    *,
+    block_size: int = 1 << 16,
+):
+    """``sssp`` (unjitted) with its counts: (dist, parent, rounds, sparse
+    rounds, reached vertices [S], reached CSR slots [S]).
+    ``TraversalEngine`` jits this one and sums the counts on the device.
+
+    Round 1 relaxes out of the sources, each later round out of the
+    vertices whose distance fell in the round before, until none falls
+    (``round_bound``).
+    Each round picks its form on the device, as ``bfs_hops`` does: while
+    the changed vertices' out-edges times ``SPARSE_EDGE_COST`` fit in the
+    padded stream it walks only their CSR rows, then the delta buffer;
+    else it sweeps every slot. Both read the round's starting distances
+    (Jacobi), and a vertex that did not change has already relaxed its
+    edges, so every round ends on the same distances in either form: those
+    of the dense loop, and the least fixpoint of ``min(dist[u] + w)``.
+
+    A lane's reached vertices are its finite distances; their CSR slots
+    are the sum of their fan-out (main arrays); inactive lanes count 0.
     """
     V = view.n_vertices
     S = source_pos.shape[0]
@@ -326,38 +380,80 @@ def sssp(
     src_b, dst_b, eid_b, nb = _blocked_coo(view, block_size)
     ecap = weight_by_row.shape[0]
     emask_rows = _full_edge_mask(view, edge_mask_by_row, ecap)
+    w_rows = weight_by_row.astype(jnp.float32)
     eid_c = jnp.clip(eid_b, 0, ecap - 1)
     ok_b = (eid_b >= 0) & jnp.take(emask_rows, eid_c)
-    w_b = jnp.where(ok_b, jnp.take(weight_by_row.astype(jnp.float32), eid_c), INF)
+    w_b = jnp.where(ok_b, jnp.take(w_rows, eid_c), INF)
     src_c = jnp.clip(src_b, 0, V - 1)
+    sparse_limit = int(nb * block_size / SPARSE_EDGE_COST)
 
+    active = source_pos >= 0
     dist0 = jnp.full((S, V), INF)
-    dist0 = dist0.at[jnp.arange(S), source_pos].set(0.0, mode="drop")
+    dist0 = dist0.at[jnp.arange(S), jnp.where(active, source_pos, V)].set(
+        0.0, mode="drop")
     dist0 = jnp.where(vmask[None, :], dist0, INF)
 
-    def relax(dist):
+    def relax_dense(dist):
         def body(i, d):
             cand = jnp.take(dist, src_c[i], axis=1) + w_b[i][None, :]
             return d.at[:, dst_b[i]].min(cand, mode="drop")
 
-        new = jax.lax.fori_loop(0, nb, body, dist)
-        return jnp.where(vmask[None, :], new, INF)
+        return jax.lax.fori_loop(0, nb, body, dist)
+
+    # the delta buffer, relaxed by the sparse form after the CSR (main only)
+    d_ok = view.delta_valid & view.gather_edge_mask(emask_rows, view.delta_eid)
+    d_src = jnp.clip(view.delta_src, 0, V - 1)
+    d_dst = jnp.where(d_ok, view.delta_dst, V)
+    d_w = jnp.take(w_rows, jnp.clip(view.delta_eid, 0, ecap - 1))
+
+    def relax_sparse(dist, changed, fired, m_c):
+        """Out of the changed vertices only: their CSR rows, whose
+        ``fired`` (fan-out) edges sum to ``m_c``, then the delta buffer."""
+        def relax(new, vert, eid, dst, ok):
+            cand = jnp.take(dist, vert, axis=1) + jnp.take(
+                w_rows, jnp.clip(eid, 0, ecap - 1))[None, :]
+            live = ok[None, :]
+            if S > 1:  # one lane: every listed vertex changed in it
+                live = live & jnp.take(changed, vert, axis=1)
+            cand = jnp.where(live, cand, INF)
+            return new.at[:, jnp.where(ok, dst, V)].min(cand, mode="drop")
+
+        new = _walk_rows(view, fired, m_c, emask_rows, relax, dist,
+                         chunk=block_size, vertex=True, scope="grf.sssp.chunk")
+        live = jnp.take(changed, d_src, axis=1) & d_ok[None, :]
+        cand = jnp.where(live, jnp.take(dist, d_src, axis=1) + d_w[None, :], INF)
+        return new.at[:, d_dst].min(cand, mode="drop")
 
     def cond(state):
-        dist, changed, it = state
-        return changed & (it < max_iters)
+        _, changed, it, _ = state
+        return jnp.any(changed) & (it < round_bound(V))
 
     def step(state):
-        dist, _, it = state
-        new = relax(dist)
-        return new, jnp.any(new < dist), it + 1
+        with jax.named_scope("grf.sssp.round"):
+            dist, changed, it, n_sparse = state
+            fired = jnp.where(jnp.any(changed, axis=0), view.fan_out, 0)
+            m_c = jnp.sum(fired)
+            sparse = m_c <= sparse_limit
+            new = jax.lax.cond(
+                sparse, lambda d: relax_sparse(d, changed, fired, m_c),
+                relax_dense, dist,
+            )
+            new = jnp.where(vmask[None, :], new, INF)
+            return new, new < dist, it + 1, n_sparse + sparse.astype(jnp.int32)
 
-    dist, _, _ = jax.lax.while_loop(cond, step, (dist0, jnp.asarray(True), jnp.int32(0)))
+    dist, _, rounds, n_sparse = jax.lax.while_loop(
+        cond, step, (dist0, jnp.isfinite(dist0), jnp.int32(0), jnp.int32(0))
+    )
     parent = _parent_pass(
         view, dist, source_pos, weight_by_row,
         edge_mask_by_row=edge_mask_by_row, block_size=block_size,
     )
-    return dist, parent
+    reached = jnp.isfinite(dist) & active[:, None]
+    return (
+        dist, parent, rounds, n_sparse,
+        jnp.sum(reached, axis=1, dtype=jnp.int32),
+        jnp.sum(jnp.where(reached, view.fan_out[None, :], 0), axis=1, dtype=jnp.int32),
+    )
 
 
 def _parent_pass(

@@ -155,8 +155,9 @@ def _trace_counted(fn, key, static_argnames=(), name=None):
 _bfs_xla = _trace_counted(
     T.bfs_hops, "traces_bfs_xla", T.BFS_STATIC_ARGNAMES, name="bfs"
 )
+# T.sssp with its round and reach counts, compiled as ``jit_sssp``
 _sssp_xla = _trace_counted(
-    T.sssp.__wrapped__, "traces_sssp_xla", T.SSSP_STATIC_ARGNAMES
+    T.sssp_rounds, "traces_sssp_xla", T.SSSP_STATIC_ARGNAMES, name="sssp"
 )
 _enum_xla = _trace_counted(
     T.enumerate_paths, "traces_enum",
@@ -199,19 +200,30 @@ class PendingQuery:
 
 
 @jax.jit
+def _tally(words, counts):
+    """Running sums that pass 2^31: ``words`` int32 [k, 2] (the sum's bits
+    above and below bit 16) plus the lane sums of ``counts`` int32 [k, S]
+    (each lane's count non-negative and int32)."""
+    lo = words[:, 1] + jnp.sum(counts & 0xFFFF, axis=1)
+    hi = words[:, 0] + jnp.sum(counts >> 16, axis=1) + (lo >> 16)
+    return jnp.stack([hi, lo & 0xFFFF], axis=1)
+
+
+@jax.jit
 def _packed_sssp_dist(
     dist0,  # f32 [S, VP] (INF init, 0 at sources, INF at masked)
     src_safe,  # int32 [F] flat packed sources (clipped)
     gdst,  # int32 [F] flat global dsts (VP = dropped)
     w,  # f32 [F] per-slot weights (INF = inactive slot)
     vmask_p,  # bool [VP]
-    max_iters,  # int32
+    max_iters,  # int32: ``T.round_bound``
 ):
-    """Jacobi scatter-min relaxation over the dst-sorted packed stream.
+    """Jacobi scatter-min relaxation over the dst-sorted packed stream,
+    until no distance falls.
 
-    Converges to the same float32 fixpoint as the blocked-COO Gauss-Seidel
-    sweep (min over identical candidate sets; float min is exact), which is
-    what makes cross-backend distances bit-identical.
+    Ends each round on the same float32 distances as the blocked-COO
+    rounds (min over identical candidate sets; float min is exact), which
+    is what makes cross-backend distances bit-identical.
     """
 
     def relax(dist):
@@ -287,6 +299,12 @@ class TraversalEngine:
         # device until ``stats`` reads them
         self._hops = jnp.zeros((), jnp.int32)
         self._sparse_hops = jnp.zeros((), jnp.int32)
+        # xla_coo SSSP rounds (all, and the frontier-sparse ones), and the
+        # vertices and CSR slots its answers reached (``_tally`` words),
+        # likewise
+        self._sssp_rounds = jnp.zeros((), jnp.int32)
+        self._sssp_sparse_rounds = jnp.zeros((), jnp.int32)
+        self._sssp_reached = jnp.zeros((2, 2), jnp.int32)
         # graph -> (topology key, avg_fan_out as a host float)
         self._fan_out: Dict[str, Tuple[Tuple, float]] = {}
         self._packs: "collections.OrderedDict" = collections.OrderedDict()
@@ -306,10 +324,21 @@ class TraversalEngine:
     def stats(self) -> collections.Counter:
         """Per-engine event counts merged with the shared trace counters.
         ``hops_xla_coo`` sums the hops every ``xla_coo`` BFS sweep ran,
-        ``hops_xla_coo_sparse`` those of them that ran frontier-sparse."""
-        hops, sparse = jax.device_get((self._hops, self._sparse_hops))
+        ``hops_xla_coo_sparse`` those of them that ran frontier-sparse;
+        ``sssp_rounds_xla_coo`` and ``sssp_rounds_xla_coo_sparse`` the same
+        for SSSP rounds, ``sssp_reached_vertices`` and
+        ``sssp_reached_slots`` the vertices those SSSPs reached (active
+        lanes) and their CSR slots."""
+        hops, sparse, rounds, sparse_rounds, reached = jax.device_get((
+            self._hops, self._sparse_hops, self._sssp_rounds,
+            self._sssp_sparse_rounds, self._sssp_reached,
+        ))
+        reached = [(int(hi) << 16) + int(lo) for hi, lo in reached]
         counted = collections.Counter(
-            hops_xla_coo=int(hops), hops_xla_coo_sparse=int(sparse)
+            hops_xla_coo=int(hops), hops_xla_coo_sparse=int(sparse),
+            sssp_rounds_xla_coo=int(rounds),
+            sssp_rounds_xla_coo_sparse=int(sparse_rounds),
+            sssp_reached_vertices=reached[0], sssp_reached_slots=reached[1],
         )
         return self._stats + counted + _TRACE_COUNTS + FS.TRACE_COUNTS
 
@@ -702,14 +731,17 @@ class TraversalEngine:
         edge_mask_by_row=None,
         vertex_mask=None,
         *,
-        max_iters: int = 64,
         backend: Optional[str] = None,
         graph: Optional[str] = None,
     ):
         """(dist f32 [S, V], parent_slot int32 [S, V]). Parents always come
         from the canonical blocked-COO parent pass, so equal distances give
         equal parents regardless of backend; a failing backend degrades
-        along ``FAILOVER_CHAIN`` rather than failing the query."""
+        along ``FAILOVER_CHAIN`` rather than failing the query.
+
+        Every backend runs rounds until no distance falls (``T.round_bound``
+        bounds them; the weights must be non-negative, which the engine
+        checks where they enter its tables)."""
         source_pos = jnp.asarray(source_pos, jnp.int32)
         weight_by_row = jnp.asarray(weight_by_row, jnp.float32)
         b = self.resolve_backend(
@@ -721,25 +753,29 @@ class TraversalEngine:
             b,
             lambda bk: self._sssp_backend(
                 bk, view, source_pos, weight_by_row, edge_mask_by_row,
-                vertex_mask, max_iters=max_iters, graph=graph,
+                vertex_mask, graph=graph,
             ),
         )
 
     def _sssp_backend(
         self, b, view, source_pos, weight_by_row, edge_mask_by_row,
-        vertex_mask, *, max_iters, graph,
+        vertex_mask, *, graph,
     ):
         """One SSSP on one specific backend (the failover unit)."""
         faults.check(SITE_DISPATCH[b])
         if b == "xla_coo":
-            return _sssp_xla(
+            dist, parent, rounds, sparse, n_vert, n_slot = _sssp_xla(
                 view, source_pos, weight_by_row, edge_mask_by_row,
-                vertex_mask, max_iters=max_iters, block_size=self._block_for(view),
+                vertex_mask, block_size=self._block_for(view),
             )
+            self._sssp_rounds = self._sssp_rounds + rounds
+            self._sssp_sparse_rounds = self._sssp_sparse_rounds + sparse
+            self._sssp_reached = _tally(self._sssp_reached, jnp.stack([n_vert, n_slot]))
+            return dist, parent
         if b == "pallas_frontier":
             dist = self._sssp_packed_dist(
                 view, source_pos, weight_by_row, edge_mask_by_row,
-                vertex_mask, max_iters=max_iters, graph=graph,
+                vertex_mask, graph=graph,
             )
         elif b == "sharded":
             ssrc, sdst, seid = self.get_shard_pack(view, graph)
@@ -750,14 +786,14 @@ class TraversalEngine:
             dist = FS.sharded_sssp_dist(
                 ssrc, sdst, seid, source_pos, weight_by_row,
                 view.n_vertices, edge_mask_by_row=edge_mask_by_row,
-                vertex_mask=vmask, max_iters=max_iters,
+                vertex_mask=vmask,
                 delta_src=dsrc, delta_dst=ddst, delta_eid=deid,
             )
         else:
             dist = jnp.asarray(
                 self._sssp_reference_dist(
                     view, source_pos, weight_by_row, edge_mask_by_row,
-                    vertex_mask, max_iters=max_iters,
+                    vertex_mask,
                 )
             )
         parent = T.sssp_parents(
@@ -768,7 +804,7 @@ class TraversalEngine:
 
     def _sssp_packed_dist(
         self, view, source_pos, weight_by_row, edge_mask_by_row,
-        vertex_mask, *, max_iters, graph,
+        vertex_mask, *, graph,
     ):
         ps, pe, ldst = self.get_pack(view, graph)
         Tt, J, BE = ps.shape
@@ -808,20 +844,22 @@ class TraversalEngine:
         vmask_p = jnp.pad(vmask, (0, VP - V), constant_values=False)
         S = source_pos.shape[0]
         dist0 = jnp.full((S, VP), _INF)
-        dist0 = dist0.at[jnp.arange(S), source_pos].set(0.0, mode="drop")
+        dist0 = dist0.at[
+            jnp.arange(S), jnp.where(source_pos >= 0, source_pos, VP)
+        ].set(0.0, mode="drop")
         dist0 = jnp.where(vmask_p[None, :], dist0, _INF)
         dist = _packed_sssp_dist(
             dist0, src_safe, gdst, w, vmask_p,
-            jnp.int32(max_iters),
+            jnp.int32(T.round_bound(V)),
         )
         return dist[:, :V]
 
     @staticmethod
     def _sssp_reference_dist(
         view, source_pos, weight_by_row, edge_mask_by_row, vertex_mask,
-        *, max_iters,
     ) -> np.ndarray:
-        """Numpy float32 Bellman-Ford to fixpoint (Jacobi sweeps)."""
+        """Numpy float32 Bellman-Ford to fixpoint (Jacobi sweeps, at most
+        ``T.round_bound``)."""
         V = view.n_vertices
         src, dst, eid = _reference_edges(view, edge_mask_by_row)
         w_rows = np.asarray(weight_by_row, np.float32)
@@ -833,7 +871,7 @@ class TraversalEngine:
         lanes = (sp >= 0) & (sp < V)
         dist[np.arange(S)[lanes], sp[lanes]] = 0.0
         dist = np.where(vmask[None, :], dist, np.inf).astype(np.float32)
-        for _ in range(max_iters):
+        for _ in range(T.round_bound(V)):
             new = dist.copy()
             for s in np.flatnonzero(np.isfinite(dist).any(axis=1)):
                 # 1-D ufunc.at per lane (numpy's fast path)
@@ -890,7 +928,6 @@ class TraversalEngine:
         self,
         *,
         max_hops: int = 16,
-        max_iters: int = 64,
         edge_mask_by_row=None,
         backend: Optional[str] = None,
         lane_width: Optional[int] = None,
@@ -959,7 +996,7 @@ class TraversalEngine:
                 dist, _ = self.sssp(
                     view, jnp.asarray(src), w,
                     edge_mask_by_row=edge_mask_by_row,
-                    max_iters=max_iters, backend=backend, graph=graph,
+                    backend=backend, graph=graph,
                 )
                 d = np.asarray(
                     jnp.take_along_axis(

@@ -278,7 +278,8 @@ def _sssp_body(
     src_c = jnp.clip(src_l, 0, V - 1)
 
     dist0 = jnp.full((S, V), _INF)
-    dist0 = dist0.at[jnp.arange(S), source_pos].set(0.0, mode="drop")
+    dist0 = dist0.at[jnp.arange(S), jnp.where(source_pos >= 0, source_pos, V)].set(
+        0.0, mode="drop")
     dist0 = jnp.where(vmask[None, :], dist0, _INF)
 
     def relax(dist):
@@ -332,12 +333,13 @@ def sharded_sssp_dist(
     edge_mask_by_row=None,
     vertex_mask=None,  # bool [V]; REQUIRED live-vertex mask from the view
     *,
-    max_iters: int = 64,
     delta_src=None,  # int32 [D] replicated delta COO (invalid: V, V, -1)
     delta_dst=None,
     delta_eid=None,
 ):
-    """Multi-device Bellman-Ford distances over an edge-cut stream.
+    """Multi-device Bellman-Ford distances over an edge-cut stream, in
+    rounds until no distance falls (at most ``n_vertices``, as
+    ``traversal.round_bound`` bounds every SSSP loop).
 
     Returns dist f32 [S, V]; parents come from the engine's canonical
     single-pass parent extraction, shared with every other backend. The
@@ -359,5 +361,5 @@ def sharded_sssp_dist(
         source_pos, weight_by_row,
         jnp.asarray(edge_mask_by_row, jnp.bool_),
         jnp.asarray(vertex_mask, jnp.bool_),
-        max_iters=max_iters,
+        max_iters=max(n_vertices, 1),
     )

@@ -159,7 +159,7 @@ def test_sssp_bit_identical_across_backends(family, seed):
     srcs = _sources(view, seed, s=4)
     out = {
         b: te.sssp(
-            view, srcs, w, edge_mask_by_row=emask, max_iters=48, backend=b
+            view, srcs, w, edge_mask_by_row=emask, backend=b
         )
         for b in BACKENDS
     }
@@ -260,7 +260,7 @@ def test_sssp_with_vertex_mask_bit_identical():
     srcs = _sources(view, 1, s=4)
     out = {
         b: te.sssp(view, srcs, w, edge_mask_by_row=emask, vertex_mask=vm,
-                   max_iters=48, backend=b)
+                   backend=b)
         for b in BACKENDS
     }
     dref, pref = (np.asarray(x) for x in out["reference"])
@@ -279,7 +279,7 @@ def test_packing_cache_hit_on_repeated_query():
     te.bfs(view, srcs, max_hops=16, backend="pallas_frontier")
     assert te.stats["pack_builds"] == 1 and te.stats["pack_hits"] == 0
     te.bfs(view, srcs, max_hops=16, backend="pallas_frontier")
-    te.sssp(view, srcs, w, max_iters=32, backend="pallas_frontier")
+    te.sssp(view, srcs, w, backend="pallas_frontier")
     assert te.stats["pack_builds"] == 1  # no re-sort
     assert te.stats["pack_hits"] == 2
     # xla_coo plan cache: same shapes => one trace across repeated queries

@@ -178,7 +178,7 @@ def _check_query(eng, te, oracle, rng, directed):
     w = et.col("w")
     out = {
         b: te.sssp(view, jnp.asarray(srcs[:3]), w, edge_mask_by_row=valid,
-                   max_iters=48, backend=b, graph="G")
+                   backend=b, graph="G")
         for b in BACKENDS
     }
     dref, pref = (np.asarray(x) for x in out["reference"])

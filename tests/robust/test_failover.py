@@ -128,7 +128,7 @@ def test_sssp_failover_bit_identical(eng, backend):
 
     def run(b):
         d, p = te.sssp(view, srcs, w, edge_mask_by_row=valid,
-                       max_iters=32, backend=b, graph="G")
+                       backend=b, graph="G")
         return np.asarray(d), np.asarray(p)
 
     dref, pref = run("reference")

@@ -55,8 +55,7 @@ def test_sssp_equivalence_with_dijkstra(seed):
     d_g = np.asarray(grail_sssp(et, "src", "dst", "weight", jnp.int32(0),
                                 n_vertices=150, n_iters=160, capacity=1 << 13))
     d_n = np.asarray(T.sssp(gv, jnp.array([0], jnp.int32),
-                            weight_by_row=jnp.asarray(ed["weight"]),
-                            max_iters=160)[0][0])
+                            weight_by_row=jnp.asarray(ed["weight"]))[0][0])
     adj = {}
     for a, b, w in zip(ed["src"], ed["dst"], ed["weight"]):
         adj.setdefault(int(a), []).append((int(b), float(w)))

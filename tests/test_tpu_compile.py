@@ -119,6 +119,20 @@ def test_engine_bfs_with_hops_compiles_at_twitter_scale(one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+def test_engine_sssp_with_rounds_compiles_at_twitter_scale(one_chip):
+    """The SSSP ``TraversalEngine`` runs: frontier-restricted rounds to
+    convergence, the parent pass and the counts, named ``jit_sssp``."""
+    view = _placed(CELL._abstract_view(), one_chip)
+    sources = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((CELL.E,), jnp.float32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((CELL.E,), jnp.bool_, sharding=one_chip)
+    compiled = TE._sssp_xla.lower(
+        view, sources, weights, mask, None, block_size=1 << 16
+    ).compile()
+    assert "jit_sssp" in compiled.as_text().splitlines()[0]
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
 def test_sharded_bfs_compiles_on_four_devices(topo, monkeypatch):
     n = 4
     mesh = Mesh(np.array(topo.devices[:n]), (TRAVERSAL_AXIS,))

@@ -63,8 +63,7 @@ def test_sssp_matches_dijkstra(g, seed):
     w = np.random.default_rng(seed).uniform(0.1, 5.0, len(edges)).astype(np.float32)
     view, _ = make_view(n, src, dst, {"w": w})
     d = np.asarray(
-        T.sssp(view, jnp.array([0], jnp.int32), weight_by_row=jnp.asarray(w),
-               max_iters=n + 2)[0][0]
+        T.sssp(view, jnp.array([0], jnp.int32), weight_by_row=jnp.asarray(w))[0][0]
     )
     adj = {}
     for a, b, ww in zip(src, dst, w):
@@ -151,7 +150,7 @@ def test_path_reconstruction():
     view, et = make_view(4, [0, 1, 2, 0], [1, 2, 3, 3],
                          {"w": np.array([1.0, 1.0, 1.0, 10.0], np.float32)})
     dist, parent = T.sssp(view, jnp.array([0], jnp.int32),
-                          weight_by_row=jnp.asarray(et.col("w")), max_iters=8)
+                          weight_by_row=jnp.asarray(et.col("w")))
     edges, verts, length = T.reconstruct_paths(
         view, parent, jnp.array([3], jnp.int32), max_len=8
     )
@@ -282,3 +281,150 @@ def test_sparse_hops_with_delta_masks_and_targets(case, hops, reached):
     assert 0 < sparse < hops  # the hub's hop stays dense
     for v, d in reached.items():
         assert ref[0, v] == d
+
+
+# --------------------------------------------------------------------------
+# frontier-restricted SSSP rounds: the same distances as an f32 Dijkstra
+# --------------------------------------------------------------------------
+_sssp_rounds = jax.jit(T.sssp_rounds, static_argnames=T.SSSP_STATIC_ARGNAMES)
+
+
+def _dijkstra_f32(view, w, srcs, emask=None, vmask=None):
+    """Dijkstra over the view's live slots, every sum rounded to float32:
+    the least fixpoint of ``d[v] = min(fl32(d[u] + w))``, lane by lane."""
+    V = view.n_vertices
+    src, dst, eid = (np.asarray(a) for a in view.all_coo())
+    ok = (eid >= 0) & (src < V) & (dst < V)
+    if emask is not None:
+        ok &= np.asarray(emask)[np.clip(eid, 0, None)]
+    live = np.asarray(view.v_valid) & (True if vmask is None else np.asarray(vmask))
+    w = np.asarray(w, np.float32)
+    adj = {}
+    for a, b, e in zip(src[ok], dst[ok], eid[ok]):
+        adj.setdefault(int(a), []).append((int(b), w[e]))
+    out = np.full((len(srcs), V), np.inf, np.float32)
+    for lane, s in enumerate(np.asarray(srcs)):
+        if s < 0 or not live[s]:
+            continue
+        d = out[lane]
+        d[s] = 0.0
+        pq = [(np.float32(0.0), int(s))]
+        while pq:
+            du, u = heapq.heappop(pq)
+            if du > d[u]:
+                continue
+            for v, ww in adj.get(u, ()):
+                nd = np.float32(du + ww)
+                if live[v] and nd < d[v]:
+                    d[v] = nd
+                    heapq.heappush(pq, (nd, v))
+    return out
+
+
+def _check_sssp(view, w, srcs, emask=None, vmask=None, *, block):
+    """dist of the frontier-restricted rounds against the f32 Dijkstra and
+    the reference backend's Jacobi rounds, bit for bit, and the counts;
+    returns (dist, rounds, sparse rounds, reached vertices, reached slots)."""
+    dist, parent, rounds, sparse, n_vert, n_slot = _sssp_rounds(
+        view, srcs, w, emask, vmask, block_size=block)
+    dist = np.asarray(dist)
+    jacobi = TraversalEngine._sssp_reference_dist(view, srcs, w, emask, vmask)
+    assert dist.tobytes() == np.asarray(jacobi, np.float32).tobytes()
+    assert dist.tobytes() == _dijkstra_f32(view, w, srcs, emask, vmask).tobytes()
+    reached = np.isfinite(dist) & (np.asarray(srcs) >= 0)[:, None]
+    fan = np.asarray(view.fan_out)
+    assert np.array_equal(np.asarray(n_vert), reached.sum(1))
+    assert np.array_equal(np.asarray(n_slot), (reached * fan).sum(1))
+    assert 0 <= int(sparse) <= int(rounds)
+    return dist, int(rounds), int(sparse), np.asarray(n_vert), np.asarray(n_slot)
+
+
+@pytest.mark.parametrize("block", [16, 1024])
+@pytest.mark.parametrize("lanes", [1, 3])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_frontier_sssp_matches_f32_dijkstra(family, lanes, block):
+    view, w, emask = build_case(family, 0)
+    srcs = _sources(view, 0, s=lanes)
+    _, rounds, sparse, *_ = _check_sssp(view, w, srcs, emask, block=block)
+    assert rounds >= 1
+    if block == 1024:  # the padded stream is large beside any round's edges
+        assert sparse == rounds
+
+
+def _weighted_hub(case, seed=0):
+    """The hub graph of the BFS tests with U[0, 1) float32 weights (a
+    quarter of them 0 in the ``zero_weights`` case) and one more feature."""
+    view, srcs, emask, vmask, _ = _hub_case(case)  # other names: the plain hub
+    rng = np.random.default_rng(seed)
+    w = rng.random(view.n_slots).astype(np.float32)
+    if case == "zero_weights":
+        w[rng.random(w.shape[0]) < 0.25] = 0.0
+    return view, srcs, jnp.asarray(w), emask, vmask
+
+
+@pytest.mark.parametrize("lanes, dense", [
+    ([0], 1),  # round 2 relaxes out of the hub
+    ([0, -1, 1], 2),  # the hub's lane relaxes it in round 1, lane 0 in round 2
+])
+def test_sssp_hub_runs_sparse_and_dense_rounds(lanes, dense):
+    view, _, w, _, _ = _weighted_hub("plain")
+    srcs = jnp.asarray(lanes, jnp.int32)
+    dist, rounds, sparse, n_vert, n_slot = _check_sssp(view, w, srcs, block=16)
+    assert 0 < sparse < rounds and sparse == rounds - dense
+    assert rounds == 5 and np.isfinite(dist[0, 203])
+    if len(lanes) > 1:
+        assert n_vert[1] == n_slot[1] == 0  # the inactive lane reaches nothing
+
+
+@pytest.mark.parametrize("case, finite, unreached", [
+    ("delta", [205], []),
+    ("tombstone", [2], [202, 203]),
+    ("edge_mask", [4, 203], [3]),
+    ("vertex_mask", [3], [2, 202, 203]),
+    ("zero_weights", [203], []),
+])
+def test_sssp_rounds_with_delta_masks_and_zero_weights(case, finite, unreached):
+    view, srcs, w, emask, vmask = _weighted_hub(case)
+    dist, rounds, sparse, *_ = _check_sssp(view, w, srcs, emask, vmask, block=16)
+    assert 0 < sparse < rounds  # the hub's round stays dense
+    assert np.isfinite(dist[0, finite]).all() and np.isinf(dist[0, unreached]).all()
+
+
+def _weighted_chain(n):
+    view, et = make_view(n, np.arange(n - 1), np.arange(1, n),
+                         {"w": np.full(n - 1, 0.5, np.float32)})
+    return view, et.col("w")
+
+
+def test_sssp_chain_past_64_rounds_converges():
+    view, w = _weighted_chain(100)  # 99 rounds lower a distance, one more finds none
+    srcs = jnp.asarray([0], jnp.int32)
+    dist, rounds, sparse, *_ = _check_sssp(view, w, srcs, block=1024)
+    assert dist[0, 99] == 49.5 and rounds == 100 == sparse
+    d, _ = T.sssp(view, srcs, w)
+    assert np.asarray(d).tobytes() == dist.tobytes()
+
+
+# --------------------------------------------------------------------------
+# the shared CSR chunk walk: BFS hops, sparse hops and distances as before
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("lanes, block, want", [
+    # (hops, sparse hops, distance sums of the first and last lane), as the
+    # sweep read before SSSP came to share its chunk walk
+    ([5], 64, (7, 5, 8560, 8560)),
+    ([5], 512, (7, 5, 8560, 8560)),
+    ([5, -1, 77], 64, (7, 4, 8560, 10276)),
+    ([5, -1, 77], 512, (7, 4, 8560, 10276)),
+])
+def test_bfs_hops_unchanged_by_the_shared_chunk_walk(lanes, block, want):
+    rng = np.random.default_rng(2024)
+    n, e = 3000, 9000
+    p = 1.0 / np.arange(1, n + 1) ** 0.8
+    p /= p.sum()
+    src = rng.choice(n, e, p=p).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    view, _ = make_view(n, src, dst, directed=False)
+    d, hops, sparse = _bfs_hops(view, jnp.asarray(lanes, jnp.int32),
+                                max_hops=24, block_size=block)
+    d = np.asarray(d).astype(np.int64)
+    assert (int(hops), int(sparse), int(d[0].sum()), int(d[-1].sum())) == want

@@ -214,7 +214,7 @@ def test_submit_sssp_merges_shared_weight_array():
     w = jnp.full((9,), 1.0, jnp.float32)
     te = TraversalEngine(lane_width=4)
     hs = [te.submit_sssp(view, 0, t, w) for t in (3, 5, 7)]
-    te.flush(max_iters=16)
+    te.flush()
     assert te.stats["queries_sssp"] == 1  # same weights object => one sweep
     assert [h.result["distance"] for h in hs] == [3.0, 5.0, 7.0]
 
@@ -225,7 +225,7 @@ def test_submit_sssp_admission():
     te = TraversalEngine(lane_width=4)
     h1 = te.submit_sssp(view, 0, 9, w)
     h2 = te.submit_sssp(view, 9, 0, w)
-    te.flush(max_iters=16)
+    te.flush()
     assert h1.result["reachable"] and h1.result["distance"] == pytest.approx(18.0)
     assert not h2.result["reachable"]
 
@@ -239,3 +239,102 @@ def test_sparse_hop_counter_sums_on_device_and_reads_with_stats():
     assert isinstance(te._sparse_hops, jax.Array) and not te.events
     stats = te.stats
     assert stats["hops_xla_coo_sparse"] == stats["hops_xla_coo"] == 6
+
+
+def test_sssp_counters_sum_on_device_and_read_with_stats():
+    view = _chain_view()  # 12 vertices, 11 edges: every round sparse
+    w = jnp.full((11,), 1.0, jnp.float32)
+    te = TraversalEngine(default_backend="xla_coo")
+    srcs = jnp.asarray([0, -1], jnp.int32)  # lane 1 inactive: reaches nothing
+    with jax.transfer_guard_device_to_host("disallow"):
+        for _ in range(3):
+            te.sssp(view, srcs, w)
+    for counter in (te._sssp_rounds, te._sssp_sparse_rounds, te._sssp_reached):
+        assert isinstance(counter, jax.Array)
+    assert not te.events
+    stats = te.stats
+    assert stats["sssp_rounds_xla_coo"] == stats["sssp_rounds_xla_coo_sparse"] == 3 * 12
+    assert stats["sssp_reached_vertices"] == 3 * 12
+    assert stats["sssp_reached_slots"] == 3 * 11  # the last vertex has no out-edge
+
+
+def test_tally_sums_past_int32():
+    te = TraversalEngine(default_backend="xla_coo")
+    from repro.core.traversal_engine import _tally
+
+    big = jnp.asarray([[2 ** 31 - 1, 5], [7, 2 ** 30]], jnp.int32)
+    for _ in range(4):
+        te._sssp_reached = _tally(te._sssp_reached, big)
+    assert te.stats["sssp_reached_vertices"] == 4 * (2 ** 31 - 1 + 5)
+    assert te.stats["sssp_reached_slots"] == 4 * (7 + 2 ** 30)
+
+
+def _weighted_chain_view(n):
+    vt = Table.create("V", {"vid": np.arange(n, dtype=np.int32)})
+    et = Table.create("E", {
+        "src": np.arange(n - 1, dtype=np.int32),
+        "dst": np.arange(1, n, dtype=np.int32),
+        "w": np.full(n - 1, 0.25, np.float32),
+    })
+    return build_graph_view("G", vt, et, v_id="vid", e_src="src", e_dst="dst"), et
+
+
+@pytest.mark.parametrize("backend", ["xla_coo", "pallas_frontier", "reference", "sharded"])
+def test_sssp_chain_past_64_rounds_converges_on_every_backend(backend):
+    view, et = _weighted_chain_view(90)
+    w = et.col("w")
+    srcs = jnp.asarray([0, 30], jnp.int32)
+    te = TraversalEngine()
+    dist, _ = te.sssp(view, srcs, w, backend=backend)
+    want = np.float32(0.25) * np.arange(90, dtype=np.float32)
+    assert np.asarray(dist)[0].tobytes() == want.tobytes()
+    assert te.stats[f"backend_{backend}"] == 1
+
+
+def _sssp_engine(n, src, dst, w, capacity=None):
+    eng = GRFusion()
+    eng.create_table("V", {"vid": np.arange(n, dtype=np.int32)})
+    eng.create_table("E", {"src": np.asarray(src, np.int32),
+                           "dst": np.asarray(dst, np.int32),
+                           "w": np.asarray(w, np.float32)}, capacity=capacity)
+    eng.create_graph_view("G", vertexes="V", edges="E", v_id="vid",
+                          e_src="src", e_dst="dst")
+    PS = P("PS")
+    q = (Query().from_paths("G", "PS")
+         .where((PS.start.id == 0) & (PS.end.id == n - 1))
+         .hint_shortest_path("w").hint_max_length(8)
+         .select(distance=col("PS.distance")))
+    return eng, q
+
+
+def test_served_sssp_past_64_rounds_converges_with_one_host_sync():
+    n = 80
+    eng, q = _sssp_engine(n, np.arange(n - 1), np.arange(1, n), np.full(n - 1, 0.5))
+    loop = eng.serving_loop()
+    loop.submit(q)
+    loop.drain()  # warm
+    before = dict(eng.events)
+    t = loop.submit(q)
+    loop.drain()
+    assert t.status == "done"
+    assert float(t.result.columns["distance"][0]) == 39.5
+    grew = {k: v - before.get(k, 0) for k, v in eng.events.items()
+            if v != before.get(k, 0) and k.startswith("host_sync.")}
+    assert grew == {"host_sync.finalize": 1}
+
+
+@pytest.mark.parametrize("enters", ["create", "insert", "update"])
+def test_shortest_path_refuses_negative_weights(enters):
+    # a negative weight on an edge of a cycle (1 <-> 2) would lower
+    # distances every round: refused where it enters the edge table
+    w = [1.0, -1.0, -1.0, 1.0] if enters == "create" else [1.0, 1.0, 1.0, 1.0]
+    eng, q = _sssp_engine(4, [0, 1, 2, 2], [1, 2, 1, 3], w, capacity=8)
+    if enters != "create":
+        assert float(eng.run(q).columns["distance"][0]) == 3.0
+    if enters == "insert":
+        eng.insert("E", {"src": np.asarray([3], np.int32), "dst": np.asarray([0], np.int32),
+                         "w": np.asarray([-0.5], np.float32)})
+    elif enters == "update":
+        eng.update_where("E", col("src") == 1, "w", -1.0)
+    with pytest.raises(ValueError, match="non-negative"):
+        eng.run(q)
